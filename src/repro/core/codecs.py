@@ -306,8 +306,8 @@ class FusedSparseCodec(UploadCodec):
     plateaus); on an overflowing plateau the fused path sheds by highest
     index where the oracle sheds smallest magnitude.
 
-    ``interpret=None`` auto-detects (CPU containers run the Pallas kernels
-    in interpret mode; TPU compiles them).
+    ``interpret=None`` picks by backend: compiled on a TPU, the Pallas
+    interpreter elsewhere (the CPU tests).
     """
 
     gamma: float = 0.1

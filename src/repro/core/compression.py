@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.packing import pack_bits, unpack_bits
+
 PyTree = Any
 
 __all__ = [
@@ -248,10 +250,7 @@ def encode_bitmap(masked: jax.Array, k: int) -> Dict[str, jax.Array]:
     dest = jnp.where(keep, slot, k)          # non-kept -> trash slot k
     vals = jnp.zeros((k + 1,), flat.dtype).at[dest].set(
         jnp.where(keep, flat, jnp.zeros_like(flat)))[:k]
-    pad = (-n) % 8
-    bits = jnp.pad(keep.astype(jnp.int32), (0, pad)).reshape(-1, 8)
-    bm = jnp.sum(bits * (1 << jnp.arange(8)), axis=1).astype(jnp.uint8)
-    return {"bitmap": bm, "values": vals,
+    return {"bitmap": pack_bits(keep), "values": vals,
             "shape": np.asarray(masked.shape, np.int32)}
 
 
@@ -293,8 +292,7 @@ def decode_bitmap(payload: Dict[str, jax.Array]) -> jax.Array:
         raise ValueError(
             f"bitmap payload has {k} value slots for a tensor of "
             f"{size} elements")
-    bits = ((bitmap.astype(jnp.int32)[:, None] >> jnp.arange(8)) & 1)
-    bits = bits.reshape(-1)
+    bits = unpack_bits(bitmap, 8 * nb)
     if _is_concrete(bits):
         b = np.asarray(bits)
         if b[size:].any():

@@ -125,7 +125,8 @@ def selective_mask_threshold(delta: jax.Array, gamma: float,
 
     When ``use_kernel`` is set, the array is routed through the segmented
     Pallas path (``ops.topk_mask_pytree`` on a single-leaf tree, DESIGN.md
-    §3.4): interpret mode on CPU, compiled on TPU.  ``iters`` maps onto the
+    §3.4): compiled through Mosaic on a TPU, run in the Pallas interpreter
+    on any other backend (the CPU tests).  ``iters`` maps onto the
     number of multi-candidate refine sweeps (each sweep resolves ~4 bits of
     threshold, vs 1 bit per bisection iter), so higher ``iters`` still buys
     tighter thresholds on the kernel path.

@@ -696,6 +696,11 @@ class FederatedServer:
         return step
 
     # ---- reporting ------------------------------------------------------
+    def compiled_hlo(self) -> List[str]:
+        """Optimized HLO text of every round program compiled so far — on a
+        TPU, each compiled Pallas kernel shows up as a ``tpu_custom_call``."""
+        return [c.as_text() for c in self._compiled.values()]
+
     def total_transport_units(self) -> float:
         """Cumulative client uploads in full-model units (Eq. 6 basis)."""
         return float(sum(r.transport_units for r in self.history))

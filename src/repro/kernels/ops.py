@@ -13,8 +13,11 @@ in a leaf-count-independent number of sweeps (DESIGN.md §3.4):
 replacing the per-leaf Python loop of O(L * (iters + 2)) sweeps and its
 per-shape ``pallas_call`` retraces.
 
-On CPU (this container) the kernels run with ``interpret=True``; on TPU they
-compile natively.  ``interpret=None`` auto-detects.
+``interpret=None`` picks by backend: on a TPU the kernels compile through
+Mosaic (``tests/test_tpu_compile.py`` compiles them for a described v5e at
+real widths; ``chip_smoke.py`` checks the compiled round holds them as
+``tpu_custom_call``s); on any other backend they run in the Pallas
+interpreter, which is how the CPU tests run them.
 """
 
 from __future__ import annotations
@@ -217,14 +220,16 @@ def _leaf_wire(flat_vals, flat_bm, ls, seg_index, gamma, wire, scales):
     byte0 = ls.offset // 8                       # offset is a SEG_LANE multiple
     nb = (size + 7) // 8
     bb = jax.lax.slice_in_dim(flat_bm, byte0, byte0 + nb)
-    bits = ((bb.astype(jnp.int32)[:, None] >> jnp.arange(8)) & 1)
-    bits = bits.reshape(-1)[:size].astype(bool)  # LSB-first, trailing pad = 0
+    bits = pk.unpack_bits(bb, size).astype(bool)  # LSB-first
 
     slot = jnp.cumsum(bits) - 1                  # index-order slot per entry
     live = bits & (slot < k)
     dest = jnp.where(live, slot, k)              # overflow -> trash slot k
-    val_buf = jnp.zeros((k + 1,), v.dtype).at[dest].set(
-        jnp.where(live, v, jnp.zeros_like(v)))
+    # int8 values scatter as int32: the TPU compiler takes ~5x longer on a
+    # sub-word scatter at embedding widths.
+    val_buf = jnp.zeros((k + 1,), jnp.int32 if v.dtype == jnp.int8
+                        else v.dtype).at[dest].set(
+        jnp.where(live, v, jnp.zeros_like(v))).astype(v.dtype)
     if scales is not None:
         values = {"q": val_buf[:k],
                   "scale": scales[seg_index].astype(jnp.float32)}
@@ -238,9 +243,7 @@ def _leaf_wire(flat_vals, flat_bm, ls, seg_index, gamma, wire, scales):
         return {"indices": idx_buf[:k], "values": values, "shape": shape}
     # bitmap wire: repack the budget-capped bits so the popcount can never
     # exceed the value slots (byte-identical to compression.encode_bitmap).
-    pad = (-size) % 8
-    capped = jnp.pad(live.astype(jnp.int32), (0, pad)).reshape(-1, 8)
-    bm = jnp.sum(capped * (1 << jnp.arange(8)), axis=1).astype(jnp.uint8)
+    bm = pk.pack_bits(live)
     return {"bitmap": bm, "values": values, "shape": shape}
 
 

@@ -40,6 +40,8 @@ __all__ = [
     "build_pack_spec",
     "pack_leaves",
     "unpack_leaves",
+    "pack_bits",
+    "unpack_bits",
 ]
 
 # Lane width of the packed buffer; also the per-leaf padding granularity.
@@ -132,3 +134,19 @@ def unpack_leaves(x2d: jax.Array, spec: PackSpec) -> List[jax.Array]:
         leaf = jax.lax.slice_in_dim(flat, ls.offset, ls.offset + ls.size)
         out.append(leaf.reshape(ls.shape).astype(ls.dtype))
     return out
+
+
+def pack_bits(bits: jax.Array) -> jax.Array:
+    """(n,) 0/1 membership -> (ceil(n / 8),) uint8, LSB-first: byte ``b``
+    bit ``j`` is element ``8 b + j``, trailing padding bits zero.  Summed
+    from eight stride-8 slices: the ``(n / 8, 8)`` reshape form takes the
+    TPU compiler minutes at embedding widths."""
+    b = jnp.pad(bits.astype(jnp.int32), (0, (-bits.shape[0]) % 8))
+    return sum(b[j::8] << j for j in range(8)).astype(jnp.uint8)
+
+
+def unpack_bits(bitmap: jax.Array, n: int) -> jax.Array:
+    """Inverse of :func:`pack_bits`: the first ``n`` membership bits as
+    int32 0/1, gathered per element (same compile-time reason)."""
+    e = jnp.arange(n, dtype=jnp.int32)
+    return (bitmap.astype(jnp.int32)[e >> 3] >> (e & 7)) & 1

@@ -86,6 +86,15 @@ SLAB_ROWS = 512
 CHUNK_ROWS = 32
 
 
+def _load_chunk(ref, c) -> jax.Array:
+    """Rows [c * CHUNK_ROWS, (c + 1) * CHUNK_ROWS) of a slab Ref, sliced on
+    the Ref itself (Mosaic lowers no dynamic_slice of a loaded value);
+    float data comes back as fp32, segment ids as int32."""
+    start = pl.multiple_of(c * CHUNK_ROWS, CHUNK_ROWS)
+    v = ref[pl.ds(start, CHUNK_ROWS), :]
+    return v if v.dtype == jnp.int32 else v.astype(jnp.float32)
+
+
 def _seg_onehot(seg: jax.Array, num_segments: int) -> jax.Array:
     """(rows, 1) int32 segment ids -> (rows, S) fp32 one-hot."""
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, num_segments), 1)
@@ -93,23 +102,26 @@ def _seg_onehot(seg: jax.Array, num_segments: int) -> jax.Array:
 
 
 def _bin_ladder() -> jax.Array:
-    """(1, 1, SEG_NBINS) fp32 bin-edge magnitudes 2^(EXPO_MIN + 4j).
+    """(1, SEG_NBINS, 1) fp32 bin-edge magnitudes 2^(EXPO_MIN + 4j).
 
     Comparing |x| against the ladder yields the SUFFIX form of the 4-octave
     exponent histogram (count per bin = adjacent difference) with SEG_NBINS
     plain compares per element — no log2/floor/one-hot chain — and zeros
     (incl. padding) fall below every edge, so they never count.
     """
-    j = jax.lax.broadcasted_iota(jnp.float32, (1, 1, SEG_NBINS), 2)
-    return jnp.exp2(j * OCTAVES_PER_BIN + EXPO_MIN)
+    # Mosaic builds integer iotas only: make it int32, then cast.
+    j = jax.lax.broadcasted_iota(jnp.int32, (1, SEG_NBINS, 1), 1)
+    return jnp.exp2((j * OCTAVES_PER_BIN + EXPO_MIN).astype(jnp.float32))
 
 
 def _row_bin_hist(x: jax.Array) -> jax.Array:
     """(rows, SEG_LANE) values -> (rows, SEG_NBINS) fp32 suffix counts:
     out[r, j] = #{e : |x[r, e]| >= 2^(EXPO_MIN + 4j)}.  fp32 sums are exact
-    (row counts <= SEG_LANE)."""
-    ge = (jnp.abs(x)[:, :, None] >= _bin_ladder()).astype(jnp.float32)
-    return jnp.sum(ge, axis=1)
+    (row counts <= SEG_LANE).  The bins ride the sublane axis and the data
+    keeps its lanes, so the (rows, SEG_NBINS, SEG_LANE) transient is dense
+    in VMEM (a minor axis of SEG_NBINS would be padded to 128 lanes)."""
+    ge = (jnp.abs(x)[:, None, :] >= _bin_ladder()).astype(jnp.float32)
+    return jnp.sum(ge, axis=2)
 
 
 # --------------------------------------------------------------------------
@@ -126,10 +138,7 @@ def _seg_hist_kernel(x_ref, seg_ref, hist_ref):
     S = hist_ref.shape[0]
 
     def chunk(c, acc):
-        xc = jax.lax.dynamic_slice_in_dim(
-            x_ref[...], c * CHUNK_ROWS, CHUNK_ROWS, 0).astype(jnp.float32)
-        sc = jax.lax.dynamic_slice_in_dim(
-            seg_ref[...], c * CHUNK_ROWS, CHUNK_ROWS, 0)
+        xc, sc = _load_chunk(x_ref, c), _load_chunk(seg_ref, c)
         row_hist = _row_bin_hist(xc)                      # (chunk, SEG_NBINS)
         seg_hot = _seg_onehot(sc, S)                      # (chunk, S)
         # scatter rows -> segments: one (S x chunk x SEG_NBINS) matmul
@@ -181,15 +190,12 @@ def _seg_count_kernel(x_ref, seg_ref, taus_ref, cnt_ref):
     S, C = taus_ref.shape
 
     def chunk(c, acc):
-        xc = jax.lax.dynamic_slice_in_dim(
-            x_ref[...], c * CHUNK_ROWS, CHUNK_ROWS, 0).astype(jnp.float32)
-        sc = jax.lax.dynamic_slice_in_dim(
-            seg_ref[...], c * CHUNK_ROWS, CHUNK_ROWS, 0)
+        xc, sc = _load_chunk(x_ref, c), _load_chunk(seg_ref, c)
         seg_hot = _seg_onehot(sc, S)                      # (chunk, S)
         taus_row = seg_hot @ taus_ref[...]                # gather: (chunk, C)
-        ge = (jnp.abs(xc)[:, :, None] >= taus_row[:, None, :]
-              ).astype(jnp.float32)                       # (chunk, LANE, C)
-        row_counts = jnp.sum(ge, axis=1)                  # (chunk, C)
+        ge = (jnp.abs(xc)[:, None, :] >= taus_row[:, :, None]
+              ).astype(jnp.float32)                       # (chunk, C, LANE)
+        row_counts = jnp.sum(ge, axis=2)                  # (chunk, C)
         return acc + jax.lax.dot_general(
             seg_hot, row_counts, dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -291,10 +297,7 @@ def _seg_stats_kernel(x_ref, seg_ref, hist_ref, amax_ref):
 
     def chunk(c, carry):
         acc, amax = carry
-        xc = jax.lax.dynamic_slice_in_dim(
-            x_ref[...], c * CHUNK_ROWS, CHUNK_ROWS, 0).astype(jnp.float32)
-        sc = jax.lax.dynamic_slice_in_dim(
-            seg_ref[...], c * CHUNK_ROWS, CHUNK_ROWS, 0)
+        xc, sc = _load_chunk(x_ref, c), _load_chunk(seg_ref, c)
         row_hist = _row_bin_hist(xc)                      # (chunk, SEG_NBINS)
         seg_hot = _seg_onehot(sc, S)                      # (chunk, S)
         acc = acc + jax.lax.dot_general(
@@ -302,15 +305,18 @@ def _seg_stats_kernel(x_ref, seg_ref, hist_ref, amax_ref):
             preferred_element_type=jnp.float32)
         # scatter-max rows -> segments: the one-hot zeroes other segments'
         # contributions, and |x| >= 0 makes max-with-zero harmless.
+        # Kept as a (1, S) row: Mosaic lays out no rank-1 vectors.
         row_amax = jnp.max(jnp.abs(xc), axis=1, keepdims=True)  # (chunk, 1)
-        amax = jnp.maximum(amax, jnp.max(seg_hot * row_amax, axis=0))
+        amax = jnp.maximum(
+            amax, jnp.max(seg_hot * row_amax, axis=0, keepdims=True))
         return acc, amax
 
     acc, amax = jax.lax.fori_loop(
         0, rows // CHUNK_ROWS, chunk,
-        (jnp.zeros(hist_ref.shape, jnp.float32), jnp.zeros((S,), jnp.float32)))
+        (jnp.zeros(hist_ref.shape, jnp.float32),
+         jnp.zeros(amax_ref.shape, jnp.float32)))
     hist_ref[...] += acc.astype(jnp.int32)
-    amax_ref[...] = jnp.maximum(amax_ref[...], amax[:, None])
+    amax_ref[...] = jnp.maximum(amax_ref[...], amax)
 
 
 def segmented_stats(x2d: jax.Array, seg_ids: jax.Array,
@@ -326,7 +332,7 @@ def segmented_stats(x2d: jax.Array, seg_ids: jax.Array,
     scale ``max|x| / 127`` needs (DESIGN.md §10), at zero extra sweeps.
     """
     slab = _slab(x2d.shape[0], slab_rows, interpret)
-    return pl.pallas_call(
+    hist, amax = pl.pallas_call(
         _seg_stats_kernel,
         grid=(x2d.shape[0] // slab,),
         in_specs=[
@@ -335,14 +341,15 @@ def segmented_stats(x2d: jax.Array, seg_ids: jax.Array,
         ],
         out_specs=(
             pl.BlockSpec((num_segments, SEG_NBINS), lambda i: (0, 0)),
-            pl.BlockSpec((num_segments, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, num_segments), lambda i: (0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((num_segments, SEG_NBINS), jnp.int32),
-            jax.ShapeDtypeStruct((num_segments, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, num_segments), jnp.float32),
         ),
         interpret=interpret,
     )(x2d, seg_ids)
+    return hist, amax.reshape(num_segments, 1)
 
 
 # --------------------------------------------------------------------------
@@ -382,14 +389,15 @@ def _seg_encode_kernel(x_ref, seg_ref, tau_ref, scale_ref,
         # Same formula as compression.quantize_int8 (round then clip), with
         # the per-segment scale gathered through the one-hot — zeros stay
         # exactly zero, so the bitmap still describes the nonzero support.
+        # Mosaic casts no f32 straight to a narrow int: go through int32.
         scale_row = seg_hot @ scale_ref[...]              # (rows, 1)
-        out_ref[...] = jnp.clip(
-            jnp.round(masked / scale_row), -127, 127).astype(jnp.int8)
+        q = jnp.clip(jnp.round(masked / scale_row), -127, 127)
+        out_ref[...] = q.astype(jnp.int32).astype(jnp.int8)
     else:
         out_ref[...] = masked.astype(out_ref.dtype)
     bm = jax.lax.dot(keep, _bit_group_weights(),
                      preferred_element_type=jnp.float32)
-    bm_ref[...] = bm.astype(jnp.uint8)
+    bm_ref[...] = bm.astype(jnp.int32).astype(jnp.uint8)
     row_kept = jnp.sum(keep, axis=1, keepdims=True)
     cnt_ref[...] += jax.lax.dot_general(
         seg_hot, row_kept, dimension_numbers=(((0,), (0,)), ((), ())),

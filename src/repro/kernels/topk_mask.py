@@ -33,31 +33,30 @@ def _grid_blocks(n_rows: int) -> int:
 # --------------------------------------------------------------------------
 # Kernel 1: per-octave magnitude histogram (one sweep of HBM).
 # --------------------------------------------------------------------------
-# Rows per factored-one-hot chunk: the (HIST_CHUNK_ROWS * LANE, NBINS/8)
-# fp32 one-hot is 64 * 1024 * 16 * 4 B = 4 MiB of VMEM transient.
+# Rows per factored-one-hot chunk: the (HIST_CHUNK_ROWS, NBINS/8, LANE)
+# fp32 one-hot is 64 * 16 * 1024 * 4 B = 4 MiB of VMEM transient.
 HIST_CHUNK_ROWS = 64
+QBINS, RBINS = NBINS // 8, 8
 
 
 def _factored_bin_counts(b: jax.Array) -> jax.Array:
-    """(rows, LANE) bin ids (-1 = none) -> (1, NBINS) fp32 counts.
+    """(rows, LANE) bin ids (-1 = none) -> (QBINS, RBINS) fp32 counts.
 
     Factored one-hot: NBINS = QBINS * RBINS, bin = 8q + r.  Two narrow
-    one-hots (16 + 8 compares per element instead of 128) contract into the
-    (QBINS, RBINS) count matrix with one matmul — MXU work on TPU.  fp32
+    one-hots (16 + 8 compares per element instead of 128) with the bins on
+    the sublane axis contract over the lane axis in one row-batched matmul
+    — MXU work on TPU, and no relayout of the (rows, LANE) tile.  fp32
     accumulation is exact (chunk counts << 2^24).
     """
-    QBINS, RBINS = NBINS // 8, 8
-    flat = b.reshape(-1, 1)
-    q_iota = jax.lax.broadcasted_iota(jnp.int32, (1, QBINS), 1)
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (1, RBINS), 1)
-    # b = -1 yields q = -1: matches no q bin, so zeros never count.
-    q_hot = (jnp.where(flat >= 0, flat // RBINS, -1) == q_iota
-             ).astype(jnp.float32)
-    r_hot = ((flat % RBINS) == r_iota).astype(jnp.float32)
+    q_iota = jax.lax.broadcasted_iota(jnp.int32, (1, QBINS, 1), 1)
+    r_iota = jax.lax.broadcasted_iota(jnp.int32, (1, RBINS, 1), 1)
+    # b = -1 shifts to q = -1: matches no q bin, so zeros never count.
+    q_hot = ((b >> 3)[:, None, :] == q_iota).astype(jnp.float32)
+    r_hot = ((b & 7)[:, None, :] == r_iota).astype(jnp.float32)
     counts = jax.lax.dot_general(
-        q_hot, r_hot, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (QBINS, RBINS)
-    return counts.reshape(1, NBINS)
+        q_hot, r_hot, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)              # (rows, QBINS, RBINS)
+    return jnp.sum(counts, axis=0)
 
 
 def _hist_kernel(x_ref, hist_ref):
@@ -67,23 +66,23 @@ def _hist_kernel(x_ref, hist_ref):
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    x = x_ref[...].astype(jnp.float32)
-    mag = jnp.abs(x)
-    valid = mag > 0.0
-    e = jnp.floor(jnp.log2(jnp.where(valid, mag, 1.0)))
-    b = jnp.clip(e.astype(jnp.int32) - EXPO_MIN, 0, NBINS - 1)
-    b = jnp.where(valid, b, -1)                   # zeros match no bin
-
     # Factored one-hot/iota bin counting, chunked over rows so the one-hot
     # transients stay in VMEM — instead of rescanning the block once per bin.
+    # Each chunk is sliced on the Ref: Mosaic lowers no dynamic_slice of a
+    # loaded value.
     def chunk(c, acc):
-        bc = jax.lax.dynamic_slice_in_dim(b, c * HIST_CHUNK_ROWS,
-                                          HIST_CHUNK_ROWS, 0)
-        return acc + _factored_bin_counts(bc)
+        start = pl.multiple_of(c * HIST_CHUNK_ROWS, HIST_CHUNK_ROWS)
+        x = x_ref[pl.ds(start, HIST_CHUNK_ROWS), :].astype(jnp.float32)
+        mag = jnp.abs(x)
+        valid = mag > 0.0
+        e = jnp.floor(jnp.log2(jnp.where(valid, mag, 1.0)))
+        b = jnp.clip(e.astype(jnp.int32) - EXPO_MIN, 0, NBINS - 1)
+        b = jnp.where(valid, b, -1)                   # zeros match no bin
+        return acc + _factored_bin_counts(b)
 
     hist_ref[...] += jax.lax.fori_loop(
         0, BLOCK_ROWS // HIST_CHUNK_ROWS, chunk,
-        jnp.zeros((1, NBINS), jnp.float32)).astype(jnp.int32)
+        jnp.zeros((QBINS, RBINS), jnp.float32)).astype(jnp.int32)
 
 
 def exponent_histogram(x2d: jax.Array, *, interpret: bool) -> jax.Array:
@@ -93,11 +92,11 @@ def exponent_histogram(x2d: jax.Array, *, interpret: bool) -> jax.Array:
         _hist_kernel,
         grid=(_grid_blocks(rows),),
         in_specs=[pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, NBINS), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, NBINS), jnp.int32),
+        out_specs=pl.BlockSpec((QBINS, RBINS), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((QBINS, RBINS), jnp.int32),
         interpret=interpret,
     )(x2d)
-    return hist[0]
+    return hist.reshape(NBINS)                    # bin = 8q + r, row-major
 
 
 # --------------------------------------------------------------------------
@@ -110,9 +109,13 @@ def _count_kernel(x_ref, tau_ref, cnt_ref):
     def _init():
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
+    # Count and threshold stay (1, 1) vectors: Mosaic stores no scalars to
+    # VMEM.  fp32 block sums are exact (BLOCK_ROWS * LANE < 2^24).
     x = x_ref[...].astype(jnp.float32)
-    tau = tau_ref[0, 0]
-    cnt_ref[0, 0] += jnp.sum(jnp.abs(x) >= tau).astype(jnp.int32)
+    ge = (jnp.abs(x) >= tau_ref[...]).astype(jnp.float32)
+    row_counts = jnp.sum(ge, axis=1, keepdims=True)              # (rows, 1)
+    cnt_ref[...] += jnp.sum(row_counts, axis=0,
+                            keepdims=True).astype(jnp.int32)
 
 
 def count_ge(x2d: jax.Array, tau: jax.Array, *, interpret: bool) -> jax.Array:
@@ -136,8 +139,7 @@ def count_ge(x2d: jax.Array, tau: jax.Array, *, interpret: bool) -> jax.Array:
 # --------------------------------------------------------------------------
 def _apply_kernel(x_ref, tau_ref, out_ref):
     x = x_ref[...]
-    tau = tau_ref[0, 0]
-    keep = (jnp.abs(x.astype(jnp.float32)) >= tau).astype(x.dtype)
+    keep = (jnp.abs(x.astype(jnp.float32)) >= tau_ref[...]).astype(x.dtype)
     out_ref[...] = x * keep
 
 

@@ -294,7 +294,6 @@ def make_cohort_fed_round(arch: ArchConfig, cfg: FedPodConfig,
     """
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     client_axis = client_axis or fed_layout(mesh)[0]
@@ -306,11 +305,11 @@ def make_cohort_fed_round(arch: ArchConfig, cfg: FedPodConfig,
 
     local_update = _make_local_update(arch, cfg, hints=hints)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(), P(client_axis), P(client_axis), P(client_axis),
                        P()),
              out_specs=(P(), P(), P()),
-             check_rep=False)
+             check_vma=False)
     def cohort_shard(params, cohort_batches, w_shard, valid_shard, key):
         # Each shard: its slice of the cohort end-to-end — local SGD, mask,
         # codec wire round-trip, weighted partial aggregation — then ONE

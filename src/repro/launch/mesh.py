@@ -21,10 +21,18 @@ HBM_BW = 819e9                # bytes/s per chip
 ICI_BW = 50e9                 # bytes/s per link (~per-chip effective)
 
 
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto: in JAX 0.9 its default is
+    Explicit axes, which the ``with_sharding_constraint`` hints of
+    ``models/hints.py`` and the fed round's ``shard_map`` reject."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_cohort_mesh(num_devices: int | None = None):

@@ -31,7 +31,9 @@ from repro.core.sampling import DynamicSampling, StaticSampling
 from repro.data.synthetic import markov_text
 from repro.launch import shardings as sh
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.fedtrain import FedPodConfig, make_fed_round
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tr
 
 
@@ -39,7 +41,7 @@ def make_mesh_arg(spec: str):
     dims = tuple(int(x) for x in spec.split("x"))
     names = {1: ("model",), 2: ("data", "model"),
              3: ("pod", "data", "model")}[len(dims)]
-    return jax.make_mesh(dims, names)
+    return make_mesh(dims, names)
 
 
 def synth_batches(cfg, batch, seq, steps, seed=0):
@@ -145,6 +147,7 @@ def main():
     ap.add_argument("--ckpt", default="")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -284,22 +284,24 @@ cohort = jax.jit(make_cohort_fed_round(cfg, fed_cfg, cohort_size=8,
                                        mesh=mesh, client_axis="data"))
 p_c, m_c = cohort(params, batches, n_samples, ids, valid, key)
 
-dmax = max(float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                 - c.astype(jnp.float32))))
-           for a, c in zip(jax.tree_util.tree_leaves(p_f),
-                           jax.tree_util.tree_leaves(p_c)))
+diffs = [np.abs(np.asarray(a, np.float32) - np.asarray(c, np.float32))
+         for a, c in zip(jax.tree_util.tree_leaves(p_f),
+                         jax.tree_util.tree_leaves(p_c))]
 print(json.dumps({"num_sampled_full": float(m_f["num_sampled"]),
                   "num_sampled_cohort": float(m_c["num_sampled"]),
                   "loss_full": float(m_f["mean_loss"]),
                   "loss_cohort": float(m_c["mean_loss"]),
-                  "dparams_max": dmax}))
+                  "dparams_max": max(float(d.max()) for d in diffs),
+                  "over_2e-3": int(sum((d > 2e-3).sum() for d in diffs)),
+                  "num_params": int(sum(d.size for d in diffs))}))
 """
 
 
 def test_sharded_cohort_round_subprocess_8dev():
     """shard_map cohort round on 8 forced host devices (1 cohort client per
     device) matches the full-population pod round: same participants, same
-    loss, params within bf16-reduction-order tolerance."""
+    loss, and params within 2e-3 except for selective-mask threshold
+    flips."""
     import json
     import os
     import subprocess
@@ -314,7 +316,13 @@ def test_sharded_cohort_round_subprocess_8dev():
     assert rec["num_sampled_full"] == rec["num_sampled_cohort"] == 5.0
     np.testing.assert_allclose(rec["loss_full"], rec["loss_cohort"],
                                rtol=1e-5)
-    assert rec["dparams_max"] < 2e-3, rec
+    # Params beyond 2e-3 are threshold flips, not upload precision (an f32
+    # upload gives the same 0.0125 max): a batch-16 and a batch-1 local
+    # update round differently, so an entry at its leaf's top-k threshold
+    # is kept by one round and dropped by the other.  16 of 623,616 entries
+    # flip, by at most 0.0125; both bounds are about 2-3x that.
+    assert rec["over_2e-3"] <= 7.5e-5 * rec["num_params"], rec
+    assert rec["dparams_max"] <= 0.025, rec
 
 
 def test_cohort_round_rejects_bad_bucket():

@@ -118,8 +118,9 @@ from repro.configs import get_arch
 from repro.configs.base import InputShape
 from repro.launch import shardings as sh, steps as steps_lib
 from repro.launch import hlo as hlo_lib
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_arch("qwen2-1.5b").reduced()
 shape = InputShape("mini", 64, 8, "train")
 hints = steps_lib.mesh_hints(mesh)
