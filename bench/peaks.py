@@ -1,0 +1,26 @@
+"""Published peaks of the chips the benchmark runs on, by JAX's
+``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip.
+A kind that is not in the table is an error, never a default.
+"""
+
+from __future__ import annotations
+
+_V5E = {"bf16_flops": 197e12, "int8_ops": 393e12,
+        "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9}
+
+PEAKS = {
+    "TPU v5 lite": _V5E,
+    "TPU v5e": _V5E,
+}
+
+
+def peak(device_kind: str) -> dict:
+    """The peak table entry of ``device_kind``; raises on an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peak for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
