@@ -89,7 +89,6 @@ def _steady_rows(server, engine, M):
         "num_sampled": recs[-1].num_sampled,
         "steady_wall_ms_per_round": round(1e3 * float(np.mean(steady)), 3),
         "compile_s": round(sum(r.compile_s for r in recs), 2),
-        "flop_proxy_per_round": recs[-1].flop_proxy,
     }
 
 
@@ -132,7 +131,6 @@ def run_cohort(Ms=(64, 256, 1024), rounds=8, smoke=False):
             "cohort_size": r.cohort_size,
             "wall_ms": round(1e3 * r.wall_s, 3),
             "compile_s": round(r.compile_s, 2),
-            "flop_proxy": r.flop_proxy,
         })
 
     from benchmarks.common import write_bench

@@ -111,7 +111,8 @@ def client_update(loss_fn: LossFn, global_params: PyTree, batches: Any,
     if residual is not None:
         delta = jax.tree.map(lambda d, r: d + r, delta, residual)
 
-    masked = mask_pytree(mask_key, delta, cfg.masking)
+    with jax.named_scope("fl.mask"):
+        masked = mask_pytree(mask_key, delta, cfg.masking)
 
     if residual is not None:
         new_residual = jax.tree.map(lambda d, m: d - m, delta, masked)
@@ -170,9 +171,9 @@ def local_update_flops(stacked_batches: Any, num_params: int,
     """Per-client FLOP proxy for one round: 6 * params * examples seen
     (fwd 2 + bwd 4 per parameter per example), times local epochs.
 
-    A proxy, not an HLO count (see launch/hlo.py for that): it is meant to
-    make per-round *relative* cost visible in RoundRecord — full-population
-    execution is flat in c(t); the cohort engine decays with it.
+    A proxy, not an HLO count (see launch/hlo.py for that): it sets a
+    client's compute time on the simulated fleet
+    (``hetero.simulate_round``, the async engine's arrival clock).
     """
     leaf = jax.tree_util.tree_leaves(stacked_batches)[0]
     # leading axes: (clients, num_batches, batch, ...)

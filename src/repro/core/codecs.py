@@ -112,8 +112,13 @@ class UploadCodec:
         raise NotImplementedError
 
     def roundtrip(self, tree: PyTree) -> PyTree:
-        """What the server sees after the upload crosses the wire."""
-        return self.decode(self.encode(tree))
+        """What the server sees after the upload crosses the wire.  The
+        two halves run under the ``fl.encode`` / ``fl.decode`` scopes, so
+        a device trace names each op's side of the wire."""
+        with jax.named_scope("fl.encode"):
+            wire = self.encode(tree)
+        with jax.named_scope("fl.decode"):
+            return self.decode(wire)
 
     def wire_bytes(self, tree: PyTree) -> int:
         """EXACT bytes of ``encode(tree)`` — shape-only (eval_shape), so it
@@ -419,5 +424,6 @@ def roundtrip_stacked(codec: UploadCodec | None, stacked: PyTree) -> PyTree:
     if codec is None or isinstance(codec, IdentityCodec):
         return stacked
     wired = jax.vmap(codec.roundtrip)(stacked)
-    return jax.tree_util.tree_map(
-        lambda w, ref: w.astype(ref.dtype), wired, stacked)
+    with jax.named_scope("fl.decode"):
+        return jax.tree_util.tree_map(
+            lambda w, ref: w.astype(ref.dtype), wired, stacked)

@@ -37,6 +37,13 @@ Every builder takes two further scenario axes (DESIGN.md §5):
 
 The pod (shard_map) form of the same round lives in
 ``repro.launch.fedtrain`` — identical math, collectives instead of vmap.
+
+Every round body names its layers with ``jax.named_scope``, so each op's
+``op_name`` (and a device trace) says which layer it belongs to:
+``fl.local_update`` (selection, batch gathers, the client updates),
+``fl.mask`` (inside it, ``client.client_update``), ``fl.encode`` /
+``fl.decode`` (``UploadCodec.roundtrip``) and ``fl.aggregate`` (the
+quarantine gate through the metrics).  Scopes change metadata only.
 """
 
 from __future__ import annotations
@@ -363,50 +370,54 @@ def make_federated_round(loss_fn: Callable, schedule: SamplingSchedule,
 
         def plain_impl(params, residuals, drift, client_batches, n_samples,
                        t, key):
-            sample_key, mask_key = jax.random.split(key)
-            part = participation_mask(sample_key, schedule, t, cfg.num_clients)
-            mask_keys = jax.random.split(mask_key, cfg.num_clients)
+            with jax.named_scope("fl.local_update"):
+                sample_key, mask_key = jax.random.split(key)
+                part = participation_mask(sample_key, schedule, t,
+                                          cfg.num_clients)
+                mask_keys = jax.random.split(mask_key, cfg.num_clients)
 
-            uploads, new_residuals, new_drift, losses = stacked_client_update(
-                loss_fn, params, client_batches, mask_keys, cfg.client,
-                residuals, cfg.error_feedback, drift)
+                (uploads, new_residuals, new_drift,
+                 losses) = stacked_client_update(
+                    loss_fn, params, client_batches, mask_keys, cfg.client,
+                    residuals, cfg.error_feedback, drift)
 
             wired = apply_wire(uploads)
-            finite = _finite_rows(wired)
-            weights = part * n_samples * finite
-            new_params = agg_fn(params, _zero_rows(wired, finite), weights,
-                                cfg.client.upload)
-            if cfg.error_feedback:
-                if wired is not uploads:
-                    # Wire loss (int8 quantisation, slot truncation) is real
-                    # masked-out mass: feed it back like any other residual so
-                    # error feedback compensates for the codec too.  Exact
-                    # no-op for bit-exact wires (u - w == 0).
-                    new_residuals = _wire_feedback(new_residuals, uploads,
-                                                   wired)
-                # Non-participants did not really run this round: keep their
-                # old residual; participants reset to the post-mask remainder.
-                # Quarantined rows count as non-participants (their whole
-                # update was discarded at the server).
-                new_residuals = _commit_rows(residuals, new_residuals,
-                                             part * finite)
-            else:
-                new_residuals = residuals
+            with jax.named_scope("fl.aggregate"):
+                finite = _finite_rows(wired)
+                weights = part * n_samples * finite
+                new_params = agg_fn(params, _zero_rows(wired, finite), weights,
+                                    cfg.client.upload)
+                if cfg.error_feedback:
+                    if wired is not uploads:
+                        # Wire loss (int8 quantisation, slot truncation) is
+                        # real masked-out mass: feed it back like any other
+                        # residual so error feedback compensates for the codec
+                        # too.  Exact no-op for bit-exact wires (u - w == 0).
+                        new_residuals = _wire_feedback(new_residuals, uploads,
+                                                       wired)
+                    # Non-participants did not really run this round: keep
+                    # their old residual; participants reset to the post-mask
+                    # remainder.  Quarantined rows count as non-participants
+                    # (their whole update was discarded at the server).
+                    new_residuals = _commit_rows(residuals, new_residuals,
+                                                 part * finite)
+                else:
+                    new_residuals = residuals
 
-            if uses_drift:
-                # Drift advances under the same gate as the residuals (the
-                # upload applied), but independent of error_feedback: h_k
-                # tracks the honest local trajectory, not the wire.
-                new_drift = _commit_rows(drift, new_drift, part * finite)
-            else:
-                new_drift = drift
+                if uses_drift:
+                    # Drift advances under the same gate as the residuals (the
+                    # upload applied), but independent of error_feedback: h_k
+                    # tracks the honest local trajectory, not the wire.
+                    new_drift = _commit_rows(drift, new_drift, part * finite)
+                else:
+                    new_drift = drift
 
-            metrics = {
-                "mean_loss": jnp.sum(losses * part)
-                / jnp.maximum(jnp.sum(part), 1.0),
-                "num_sampled": jnp.sum(part),
-                "quarantined": jnp.sum(part * (1.0 - finite)),
-            }
+                metrics = {
+                    "mean_loss": jnp.sum(losses * part)
+                    / jnp.maximum(jnp.sum(part), 1.0),
+                    "num_sampled": jnp.sum(part),
+                    "quarantined": jnp.sum(part * (1.0 - finite)),
+                }
             return new_params, new_residuals, new_drift, metrics
 
         return _wrap_plain(plain_impl, uses_drift)
@@ -421,15 +432,16 @@ def make_federated_round(loss_fn: Callable, schedule: SamplingSchedule,
     def round_impl(params, residuals, drift, norms, client_batches,
                    n_samples, t, key):
         M = cfg.num_clients
-        sample_key, mask_key, drop_key = _split_round_key(
-            key, drop is not None)
-        part, weights = smp.select(sample_key, schedule, t, M, n_samples,
-                                   norms)
-        mask_keys = jax.random.split(mask_key, M)
+        with jax.named_scope("fl.local_update"):
+            sample_key, mask_key, drop_key = _split_round_key(
+                key, drop is not None)
+            part, weights = smp.select(sample_key, schedule, t, M, n_samples,
+                                       norms)
+            mask_keys = jax.random.split(mask_key, M)
 
-        uploads, new_residuals, new_drift, losses = stacked_client_update(
-            loss_fn, params, client_batches, mask_keys, cfg.client,
-            residuals, cfg.error_feedback, drift)
+            uploads, new_residuals, new_drift, losses = stacked_client_update(
+                loss_fn, params, client_batches, mask_keys, cfg.client,
+                residuals, cfg.error_feedback, drift)
 
         wired = apply_wire(uploads)
         # What the server decodes: adversary rows perturbed, then the
@@ -437,59 +449,60 @@ def make_federated_round(loss_fn: Callable, schedule: SamplingSchedule,
         # the HONEST (uploads, wired) pair — a client's residual reflects
         # what IT failed to ship, not what an attacker forged in its name.
         payload = _attack_payload(attack, wired, adv, mask_key, M)
-        finite = _finite_rows(payload)
-        arrived, weights = _apply_dropout(part, weights, drop, drop_key,
-                                          smp.normalize)
-        weights = weights * finite
-        new_params = agg_fn(params, _zero_rows(payload, finite), weights,
-                            cfg.client.upload)
-        if cfg.error_feedback:
-            if wired is not uploads:
-                new_residuals = _wire_feedback(new_residuals, uploads,
-                                               wired)
-            # Residuals advance only for clients whose upload ARRIVED (and
-            # survived quarantine): a dropped upload discards the whole
-            # local update, so its residual must stay consistent with the
-            # global model the client re-downloads next round.
-            new_residuals = _commit_rows(residuals, new_residuals,
-                                         arrived * finite)
-        else:
-            new_residuals = residuals
+        with jax.named_scope("fl.aggregate"):
+            finite = _finite_rows(payload)
+            arrived, weights = _apply_dropout(part, weights, drop, drop_key,
+                                              smp.normalize)
+            weights = weights * finite
+            new_params = agg_fn(params, _zero_rows(payload, finite), weights,
+                                cfg.client.upload)
+            if cfg.error_feedback:
+                if wired is not uploads:
+                    new_residuals = _wire_feedback(new_residuals, uploads,
+                                                   wired)
+                # Residuals advance only for clients whose upload ARRIVED (and
+                # survived quarantine): a dropped upload discards the whole
+                # local update, so its residual must stay consistent with the
+                # global model the client re-downloads next round.
+                new_residuals = _commit_rows(residuals, new_residuals,
+                                             arrived * finite)
+            else:
+                new_residuals = residuals
 
-        if uses_drift:
-            # Same arrival gate as the residuals; independent of
-            # error_feedback (drift tracks the honest local trajectory).
-            new_drift = _commit_rows(drift, new_drift, arrived * finite)
-        else:
-            new_drift = drift
+            if uses_drift:
+                # Same arrival gate as the residuals; independent of
+                # error_feedback (drift tracks the honest local trajectory).
+                new_drift = _commit_rows(drift, new_drift, arrived * finite)
+            else:
+                new_drift = drift
 
-        new_norms = norms
-        if smp.adaptive:
-            # The tracker observes what the server saw — attacked rows feed
-            # their forged norms in, exactly the signal a norm-adaptive
-            # sampler would really receive under attack.
-            obs = _row_l2(payload)
-            new_norms = jnp.where(
-                arrived * finite > 0,
-                (1.0 - smp.ema) * norms + smp.ema * obs, norms)
+            new_norms = norms
+            if smp.adaptive:
+                # The tracker observes what the server saw — attacked rows
+                # feed their forged norms in, exactly the signal a
+                # norm-adaptive sampler would really receive under attack.
+                obs = _row_l2(payload)
+                new_norms = jnp.where(
+                    arrived * finite > 0,
+                    (1.0 - smp.ema) * norms + smp.ema * obs, norms)
 
-        # An empty round (the threshold sampler's random count can be 0) is
-        # a no-op for the params; report NaN, not a fabricated 0.0 loss.
-        n_part = jnp.sum(part)
-        metrics = {
-            "mean_loss": jnp.where(
-                n_part > 0,
-                jnp.sum(losses * part) / jnp.maximum(n_part, 1.0),
-                jnp.nan),
-            "num_sampled": n_part,
-            "quarantined": jnp.sum(arrived * (1.0 - finite)),
-        }
-        if attack is not None:
-            metrics["num_adversarial"] = jnp.sum(part * adv)
-        if drop is not None:
-            metrics["part_mask"] = part
-            metrics["arrived_mask"] = arrived
-            metrics["num_arrived"] = jnp.sum(arrived)
+            # An empty round (the threshold sampler's random count can be 0) is
+            # a no-op for the params; report NaN, not a fabricated 0.0 loss.
+            n_part = jnp.sum(part)
+            metrics = {
+                "mean_loss": jnp.where(
+                    n_part > 0,
+                    jnp.sum(losses * part) / jnp.maximum(n_part, 1.0),
+                    jnp.nan),
+                "num_sampled": n_part,
+                "quarantined": jnp.sum(arrived * (1.0 - finite)),
+            }
+            if attack is not None:
+                metrics["num_adversarial"] = jnp.sum(part * adv)
+            if drop is not None:
+                metrics["part_mask"] = part
+                metrics["arrived_mask"] = arrived
+                metrics["num_arrived"] = jnp.sum(arrived)
         return new_params, new_residuals, new_drift, new_norms, metrics
 
     return _wrap_round(round_impl, uses_drift, smp.adaptive)
@@ -571,27 +584,28 @@ def make_cohort_compute(loss_fn: Callable, schedule: SamplingSchedule,
 
     def compute(params, residuals, drift, norms, client_batches, n_samples,
                 t, sample_key, mask_key):
-        M = cfg.num_clients
-        # Selection runs on the full (M,) arrays — identical ops to the
-        # oracle — then the cohort buffer gathers the sampler's ids.
-        part, weights = smp.select(sample_key, schedule, t, M, n_samples,
-                                   norms)
-        ids = jnp.arange(M, dtype=jnp.int32)
-        order = jnp.argsort(jnp.where(part > 0, ids, ids + M))
-        cohort_ids = jnp.sort(order[:cohort_size])
+        with jax.named_scope("fl.local_update"):
+            M = cfg.num_clients
+            # Selection runs on the full (M,) arrays — identical ops to the
+            # oracle — then the cohort buffer gathers the sampler's ids.
+            part, weights = smp.select(sample_key, schedule, t, M, n_samples,
+                                       norms)
+            ids = jnp.arange(M, dtype=jnp.int32)
+            order = jnp.argsort(jnp.where(part > 0, ids, ids + M))
+            cohort_ids = jnp.sort(order[:cohort_size])
 
-        def gather(x):
-            return jnp.take(x, cohort_ids, axis=0)
+            def gather(x):
+                return jnp.take(x, cohort_ids, axis=0)
 
-        cohort_batches = jax.tree.map(gather, client_batches)
-        cohort_res = jax.tree.map(gather, residuals)
-        cohort_drift = jax.tree.map(gather, drift)  # None stays None
-        mask_keys = jnp.take(
-            jax.random.split(mask_key, M), cohort_ids, axis=0)
+            cohort_batches = jax.tree.map(gather, client_batches)
+            cohort_res = jax.tree.map(gather, residuals)
+            cohort_drift = jax.tree.map(gather, drift)  # None stays None
+            mask_keys = jnp.take(
+                jax.random.split(mask_key, M), cohort_ids, axis=0)
 
-        uploads, new_res, new_drift, losses = stacked_client_update(
-            loss_fn, params, cohort_batches, mask_keys, cfg.client,
-            cohort_res, cfg.error_feedback, cohort_drift)
+            uploads, new_res, new_drift, losses = stacked_client_update(
+                loss_fn, params, cohort_batches, mask_keys, cfg.client,
+                cohort_res, cfg.error_feedback, cohort_drift)
 
         wired = roundtrip_stacked(codec, uploads)
         attacked = _attack_payload(attack, wired, adv, mask_key, M,
@@ -642,60 +656,63 @@ def make_cohort_round(loss_fn: Callable, schedule: SamplingSchedule,
 
         def plain_impl(params, residuals, drift, client_batches, n_samples,
                        t, key):
-            sample_key, mask_key = jax.random.split(key)
-            cohort_ids, valid = cohort_select(
-                sample_key, schedule, t, cfg.num_clients, cohort_size)
+            with jax.named_scope("fl.local_update"):
+                sample_key, mask_key = jax.random.split(key)
+                cohort_ids, valid = cohort_select(
+                    sample_key, schedule, t, cfg.num_clients, cohort_size)
 
-            def gather(x):
-                return jnp.take(x, cohort_ids, axis=0)
+                def gather(x):
+                    return jnp.take(x, cohort_ids, axis=0)
 
-            cohort_batches = jax.tree.map(gather, client_batches)
-            cohort_res = jax.tree.map(gather, residuals)
-            cohort_drift = jax.tree.map(gather, drift)
-            mask_keys = jnp.take(
-                jax.random.split(mask_key, cfg.num_clients), cohort_ids,
-                axis=0)
+                cohort_batches = jax.tree.map(gather, client_batches)
+                cohort_res = jax.tree.map(gather, residuals)
+                cohort_drift = jax.tree.map(gather, drift)
+                mask_keys = jnp.take(
+                    jax.random.split(mask_key, cfg.num_clients), cohort_ids,
+                    axis=0)
 
-            uploads, new_res, new_drift, losses = stacked_client_update(
-                loss_fn, params, cohort_batches, mask_keys, cfg.client,
-                cohort_res, cfg.error_feedback, cohort_drift)
+                uploads, new_res, new_drift, losses = stacked_client_update(
+                    loss_fn, params, cohort_batches, mask_keys, cfg.client,
+                    cohort_res, cfg.error_feedback, cohort_drift)
 
             wired = apply_wire(uploads)
-            finite = _finite_rows(wired)
-            weights = valid * jnp.take(n_samples, cohort_ids) * finite
-            new_params = agg_fn(params, _zero_rows(wired, finite), weights,
-                                cfg.client.upload)
+            with jax.named_scope("fl.aggregate"):
+                finite = _finite_rows(wired)
+                weights = valid * jnp.take(n_samples, cohort_ids) * finite
+                new_params = agg_fn(params, _zero_rows(wired, finite), weights,
+                                    cfg.client.upload)
 
-            def scatter_back(full_old, rows, cohort_old, commit):
-                def scatter(old, new, old_cohort):
-                    vm = commit.reshape((-1,) + (1,) * (new.ndim - 1))
-                    kept = jnp.where(vm > 0, new, old_cohort)
-                    return old.at[cohort_ids].set(kept)
+                def scatter_back(full_old, rows, cohort_old, commit):
+                    def scatter(old, new, old_cohort):
+                        vm = commit.reshape((-1,) + (1,) * (new.ndim - 1))
+                        kept = jnp.where(vm > 0, new, old_cohort)
+                        return old.at[cohort_ids].set(kept)
 
-                return jax.tree.map(scatter, full_old, rows, cohort_old)
+                    return jax.tree.map(scatter, full_old, rows, cohort_old)
 
-            if cfg.error_feedback:
-                if wired is not uploads:
-                    # Same wire-loss feedback as the oracle round (bit-exact
-                    # equivalence holds: both engines adjust identically).
-                    new_res = _wire_feedback(new_res, uploads, wired)
-                new_residuals = scatter_back(residuals, new_res, cohort_res,
+                if cfg.error_feedback:
+                    if wired is not uploads:
+                        # Same wire-loss feedback as the oracle round
+                        # (bit-exact equivalence holds: both engines adjust
+                        # identically).
+                        new_res = _wire_feedback(new_res, uploads, wired)
+                    new_residuals = scatter_back(residuals, new_res,
+                                                 cohort_res, valid * finite)
+                else:
+                    new_residuals = residuals
+
+                if uses_drift:
+                    new_drift = scatter_back(drift, new_drift, cohort_drift,
                                              valid * finite)
-            else:
-                new_residuals = residuals
+                else:
+                    new_drift = drift
 
-            if uses_drift:
-                new_drift = scatter_back(drift, new_drift, cohort_drift,
-                                         valid * finite)
-            else:
-                new_drift = drift
-
-            metrics = {
-                "mean_loss": jnp.sum(losses * valid)
-                / jnp.maximum(jnp.sum(valid), 1.0),
-                "num_sampled": jnp.sum(valid),
-                "quarantined": jnp.sum(valid * (1.0 - finite)),
-            }
+                metrics = {
+                    "mean_loss": jnp.sum(losses * valid)
+                    / jnp.maximum(jnp.sum(valid), 1.0),
+                    "num_sampled": jnp.sum(valid),
+                    "quarantined": jnp.sum(valid * (1.0 - finite)),
+                }
             return new_params, new_residuals, new_drift, metrics
 
         return _wrap_plain(plain_impl, uses_drift)
@@ -711,8 +728,9 @@ def make_cohort_round(loss_fn: Callable, schedule: SamplingSchedule,
 
     def round_impl(params, residuals, drift, norms, client_batches,
                    n_samples, t, key):
-        sample_key, mask_key, drop_key = _split_round_key(
-            key, drop is not None)
+        with jax.named_scope("fl.local_update"):
+            sample_key, mask_key, drop_key = _split_round_key(
+                key, drop is not None)
         # The client-side sweep (selection → gather → updates → wire →
         # adversary injection) is the engine-shared compute; everything
         # below is this engine's barrier: dropout draw, quarantine gate,
@@ -722,67 +740,68 @@ def make_cohort_round(loss_fn: Callable, schedule: SamplingSchedule,
         part, cohort_ids = c["part"], c["cohort_ids"]
         uploads, new_res, wired = c["uploads"], c["new_res"], c["wired"]
         losses, payload = c["losses"], c["attacked"]
-        finite = _finite_rows(payload)
-        arrived, weights = _apply_dropout(part, c["weights"], drop, drop_key,
-                                          smp.normalize)
+        with jax.named_scope("fl.aggregate"):
+            finite = _finite_rows(payload)
+            arrived, weights = _apply_dropout(part, c["weights"], drop,
+                                              drop_key, smp.normalize)
 
-        def gather(x):
-            return jnp.take(x, cohort_ids, axis=0)
+            def gather(x):
+                return jnp.take(x, cohort_ids, axis=0)
 
-        valid = gather(part)
-        arr_c = gather(arrived)
-        w_c = gather(weights) * finite
-        new_params = agg_fn(params, _zero_rows(payload, finite), w_c,
-                            cfg.client.upload)
+            valid = gather(part)
+            arr_c = gather(arrived)
+            w_c = gather(weights) * finite
+            new_params = agg_fn(params, _zero_rows(payload, finite), w_c,
+                                cfg.client.upload)
 
-        def scatter_back(full_old, rows, cohort_old, commit):
-            def scatter(old, new, old_cohort):
-                am = commit.reshape((-1,) + (1,) * (new.ndim - 1))
-                kept = jnp.where(am > 0, new, old_cohort)
-                return old.at[cohort_ids].set(kept)
+            def scatter_back(full_old, rows, cohort_old, commit):
+                def scatter(old, new, old_cohort):
+                    am = commit.reshape((-1,) + (1,) * (new.ndim - 1))
+                    kept = jnp.where(am > 0, new, old_cohort)
+                    return old.at[cohort_ids].set(kept)
 
-            return jax.tree.map(scatter, full_old, rows, cohort_old)
+                return jax.tree.map(scatter, full_old, rows, cohort_old)
 
-        if cfg.error_feedback:
-            # EF feedback stays on the HONEST (uploads, wired) pair — see
-            # the oracle body.
-            if wired is not uploads:
-                new_res = _wire_feedback(new_res, uploads, wired)
-            new_residuals = scatter_back(residuals, new_res, c["cohort_res"],
-                                         arr_c * finite)
-        else:
-            new_residuals = residuals
+            if cfg.error_feedback:
+                # EF feedback stays on the HONEST (uploads, wired) pair — see
+                # the oracle body.
+                if wired is not uploads:
+                    new_res = _wire_feedback(new_res, uploads, wired)
+                new_residuals = scatter_back(residuals, new_res,
+                                             c["cohort_res"], arr_c * finite)
+            else:
+                new_residuals = residuals
 
-        if uses_drift:
-            new_drift = scatter_back(drift, c["new_drift"],
-                                     c["cohort_drift"], arr_c * finite)
-        else:
-            new_drift = drift
+            if uses_drift:
+                new_drift = scatter_back(drift, c["new_drift"],
+                                         c["cohort_drift"], arr_c * finite)
+            else:
+                new_drift = drift
 
-        new_norms = norms
-        if smp.adaptive:
-            obs = _row_l2(payload)
-            old_c = gather(norms)
-            upd = jnp.where(arr_c * finite > 0,
-                            (1.0 - smp.ema) * old_c + smp.ema * obs, old_c)
-            new_norms = norms.at[cohort_ids].set(upd)
+            new_norms = norms
+            if smp.adaptive:
+                obs = _row_l2(payload)
+                old_c = gather(norms)
+                upd = jnp.where(arr_c * finite > 0,
+                                (1.0 - smp.ema) * old_c + smp.ema * obs, old_c)
+                new_norms = norms.at[cohort_ids].set(upd)
 
-        # Same empty-round convention as the oracle body: NaN, not 0.0.
-        n_part = jnp.sum(part)
-        metrics = {
-            "mean_loss": jnp.where(
-                n_part > 0,
-                jnp.sum(losses * valid) / jnp.maximum(jnp.sum(valid), 1.0),
-                jnp.nan),
-            "num_sampled": n_part,
-            "quarantined": jnp.sum(arr_c * (1.0 - finite)),
-        }
-        if attack is not None:
-            metrics["num_adversarial"] = jnp.sum(part * adv)
-        if drop is not None:
-            metrics["part_mask"] = part
-            metrics["arrived_mask"] = arrived
-            metrics["num_arrived"] = jnp.sum(arrived)
+            # Same empty-round convention as the oracle body: NaN, not 0.0.
+            n_part = jnp.sum(part)
+            metrics = {
+                "mean_loss": jnp.where(
+                    n_part > 0,
+                    jnp.sum(losses * valid) / jnp.maximum(jnp.sum(valid), 1.0),
+                    jnp.nan),
+                "num_sampled": n_part,
+                "quarantined": jnp.sum(arr_c * (1.0 - finite)),
+            }
+            if attack is not None:
+                metrics["num_adversarial"] = jnp.sum(part * adv)
+            if drop is not None:
+                metrics["part_mask"] = part
+                metrics["arrived_mask"] = arrived
+                metrics["num_arrived"] = jnp.sum(arrived)
         return new_params, new_residuals, new_drift, new_norms, metrics
 
     return _wrap_round(round_impl, uses_drift, smp.adaptive)
@@ -883,12 +902,13 @@ def make_store_selection(schedule: SamplingSchedule, cfg: FederatedConfig,
     smp = sampler if sampler is not None else UniformSampler()
 
     def select(norms, n_samples, t, sample_key):
-        M = cfg.num_clients
-        part, weights = smp.select(sample_key, schedule, t, M, n_samples,
-                                   norms)
-        ids = jnp.arange(M, dtype=jnp.int32)
-        order = jnp.argsort(jnp.where(part > 0, ids, ids + M))
-        cohort_ids = jnp.sort(order[:cohort_size])
+        with jax.named_scope("fl.local_update"):
+            M = cfg.num_clients
+            part, weights = smp.select(sample_key, schedule, t, M, n_samples,
+                                       norms)
+            ids = jnp.arange(M, dtype=jnp.int32)
+            order = jnp.argsort(jnp.where(part > 0, ids, ids + M))
+            cohort_ids = jnp.sort(order[:cohort_size])
         return part, weights, cohort_ids
 
     return select
@@ -918,12 +938,13 @@ def make_store_compute(loss_fn: Callable, cfg: FederatedConfig, *,
 
     def compute(params, cohort_res, cohort_batches, cohort_ids, mask_key,
                 cohort_drift=None):
-        M = cfg.num_clients
-        mask_keys = jnp.take(
-            jax.random.split(mask_key, M), cohort_ids, axis=0)
-        uploads, new_res, new_drift, losses = stacked_client_update(
-            loss_fn, params, cohort_batches, mask_keys, cfg.client,
-            cohort_res, cfg.error_feedback, cohort_drift)
+        with jax.named_scope("fl.local_update"):
+            M = cfg.num_clients
+            mask_keys = jnp.take(
+                jax.random.split(mask_key, M), cohort_ids, axis=0)
+            uploads, new_res, new_drift, losses = stacked_client_update(
+                loss_fn, params, cohort_batches, mask_keys, cfg.client,
+                cohort_res, cfg.error_feedback, cohort_drift)
         wired = roundtrip_stacked(codec, uploads)
         attacked = _attack_payload(attack, wired, adv, mask_key, M,
                                    cohort_ids=cohort_ids)
@@ -1003,48 +1024,49 @@ def make_store_round(loss_fn: Callable, schedule: SamplingSchedule,
         uploads, new_res, wired = c["uploads"], c["new_res"], c["wired"]
         losses, payload = c["losses"], c["attacked"]
         drift_rows = c["new_drift"]
-        finite = _finite_rows(payload)
-        arrived, weights = _apply_dropout(part, weights, drop, drop_key,
-                                          smp.normalize)
+        with jax.named_scope("fl.aggregate"):
+            finite = _finite_rows(payload)
+            arrived, weights = _apply_dropout(part, weights, drop, drop_key,
+                                              smp.normalize)
 
-        def gather(x):
-            return jnp.take(x, cohort_ids, axis=0)
+            def gather(x):
+                return jnp.take(x, cohort_ids, axis=0)
 
-        valid = gather(part)
-        arr_c = gather(arrived)
-        w_c = gather(weights) * finite
-        new_params = agg_fn(params, _zero_rows(payload, finite), w_c,
-                            cfg.client.upload)
-        commit = arr_c * finite
-        if cfg.error_feedback:
-            # EF feedback on the HONEST (uploads, wired) pair, exactly as
-            # in the in-program engines.
-            if wired is not uploads:
-                new_res = _wire_feedback(new_res, uploads, wired)
+            valid = gather(part)
+            arr_c = gather(arrived)
+            w_c = gather(weights) * finite
+            new_params = agg_fn(params, _zero_rows(payload, finite), w_c,
+                                cfg.client.upload)
+            commit = arr_c * finite
+            if cfg.error_feedback:
+                # EF feedback on the HONEST (uploads, wired) pair, exactly as
+                # in the in-program engines.
+                if wired is not uploads:
+                    new_res = _wire_feedback(new_res, uploads, wired)
 
-        norm_upd = None
-        if smp.adaptive:
-            obs = _row_l2(payload)
-            old_c = gather(norms)
-            norm_upd = jnp.where(arr_c * finite > 0,
-                                 (1.0 - smp.ema) * old_c + smp.ema * obs,
-                                 old_c)
+            norm_upd = None
+            if smp.adaptive:
+                obs = _row_l2(payload)
+                old_c = gather(norms)
+                norm_upd = jnp.where(arr_c * finite > 0,
+                                     (1.0 - smp.ema) * old_c + smp.ema * obs,
+                                     old_c)
 
-        n_part = jnp.sum(part)
-        metrics = {
-            "mean_loss": jnp.where(
-                n_part > 0,
-                jnp.sum(losses * valid) / jnp.maximum(jnp.sum(valid), 1.0),
-                jnp.nan),
-            "num_sampled": n_part,
-            "quarantined": jnp.sum(arr_c * (1.0 - finite)),
-        }
-        if attack is not None:
-            metrics["num_adversarial"] = jnp.sum(part * adv)
-        if drop is not None:
-            metrics["part_mask"] = part
-            metrics["arrived_mask"] = arrived
-            metrics["num_arrived"] = jnp.sum(arrived)
+            n_part = jnp.sum(part)
+            metrics = {
+                "mean_loss": jnp.where(
+                    n_part > 0,
+                    jnp.sum(losses * valid) / jnp.maximum(jnp.sum(valid), 1.0),
+                    jnp.nan),
+                "num_sampled": n_part,
+                "quarantined": jnp.sum(arr_c * (1.0 - finite)),
+            }
+            if attack is not None:
+                metrics["num_adversarial"] = jnp.sum(part * adv)
+            if drop is not None:
+                metrics["part_mask"] = part
+                metrics["arrived_mask"] = arrived
+                metrics["num_arrived"] = jnp.sum(arrived)
         return new_params, new_res, drift_rows, commit, norm_upd, metrics
 
     return StoreRound(select=select, body=body, adaptive=smp.adaptive,

@@ -102,7 +102,6 @@ class RoundRecord:
     wall_s: float = 0.0         # steady-state execution time (compile excluded)
     compile_s: float = 0.0      # program build time; nonzero on bucket-change rounds
     cohort_size: int = 0        # padded cohort buffer actually executed
-    flop_proxy: float = 0.0     # 6·params·examples·epochs·cohort_size (proxy)
     sim_round_s: float = 0.0    # simulated fleet wall-clock (hetero only)
     straggler_s: float = 0.0    # sim straggler tail: max - median arrival
     dropped: int = 0            # uploads lost on the simulated fleet
@@ -299,10 +298,11 @@ class FederatedServer:
         hit = self._compiled.get(cache_key)
         if hit is not None:
             return hit, 0.0
-        fn = self._round_program(bucket, seg_len)
-        t0 = time.perf_counter()
-        compiled = jax.jit(fn).lower(*args).compile()
-        compile_s = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("fl.compile", bucket=bucket):
+            fn = self._round_program(bucket, seg_len)
+            t0 = time.perf_counter()
+            compiled = jax.jit(fn).lower(*args).compile()
+            compile_s = time.perf_counter() - t0
         self._compiled[cache_key] = compiled
         return compiled, compile_s
 
@@ -381,81 +381,113 @@ class FederatedServer:
                                    eval_rounds, eval_data, gamma, wire_bytes,
                                    flops_per_client)
 
-        for bucket, ts in self._segments(rounds, eval_rounds, start):
+        # Host spans on the profiler's clock, beside the device ops:
+        # ``fl.plan`` (the schedule's cohort sizes and buckets), then
+        # ``fl.round`` per segment; inside it ``fl.launch`` (key split,
+        # argument building, executable lookup, dispatch), ``fl.wait`` and
+        # ``fl.readback`` (metric pulls, records, eval).
+        with jax.profiler.TraceAnnotation("fl.plan"):
+            segments = self._segments(rounds, eval_rounds, start)
+        for bucket, ts in segments:
             seg_len = len(ts)
-            subs = []
-            for _ in ts:
-                self._key, sub = jax.random.split(self._key)
-                subs.append(sub)
-            if seg_len > 1:
-                t_arg = jnp.asarray(ts, jnp.float32)
-                key_arg = jnp.stack(subs)
-            else:
-                t_arg = jnp.asarray(ts[0], jnp.float32)
-                key_arg = subs[0]
-            # Engine-wide state convention: (params, residuals[, drift]
-            # [, norms]) — optional slots appear only when the strategy
-            # carries that state, so historical programs are unchanged.
-            state = [self.params, self._residuals]
-            if self._uses_drift:
-                state.append(self._drift)
-            if self._adaptive:
-                state.append(self._norms)
-            args = (*state, client_batches, n_samples, t_arg, key_arg)
-            compiled, compile_s = self._get_compiled(bucket, seg_len, args)
-            t0 = time.perf_counter()
-            out = compiled(*args)
-            self.params, self._residuals = out[0], out[1]
-            i = 2
-            if self._uses_drift:
-                self._drift = out[i]
-                i += 1
-            if self._adaptive:
-                self._norms = out[i]
-                i += 1
-            metrics = out[i]
-            jax.block_until_ready(self.params)
-            wall = time.perf_counter() - t0
-
-            num_sampled = np.atleast_1d(np.asarray(metrics["num_sampled"]))
-            mean_loss = np.atleast_1d(np.asarray(metrics["mean_loss"]))
-            quarantined = np.atleast_1d(np.asarray(metrics["quarantined"]))
-            adversarial = None
-            if "num_adversarial" in metrics:
-                adversarial = np.atleast_1d(
-                    np.asarray(metrics["num_adversarial"]))
-            if self._traits is not None:
-                part_masks = np.atleast_2d(np.asarray(metrics["part_mask"]))
-                arrived_masks = np.atleast_2d(
-                    np.asarray(metrics["arrived_mask"]))
-            for i, t in enumerate(ts):
-                m = float(num_sampled[i])
-                rec = RoundRecord(
-                    round=t,
-                    num_sampled=int(m),
-                    mean_loss=float(mean_loss[i]),
-                    transport_units=m * gamma,
-                    transport_bytes=int(m) * wire_bytes,
-                    wall_s=wall / seg_len,
-                    compile_s=compile_s if i == 0 else 0.0,
-                    cohort_size=bucket,
-                    flop_proxy=float(flops_per_client) * bucket,
-                    quarantined=int(quarantined[i]),
-                    adversarial=(int(adversarial[i])
-                                 if adversarial is not None else 0),
-                )
-                if self._traits is not None:
-                    sim = simulate_round(self._traits, part_masks[i],
-                                         arrived_masks[i],
-                                         float(flops_per_client), wire_bytes)
-                    rec.sim_round_s = sim["sim_round_s"]
-                    rec.straggler_s = sim["straggler_s"]
-                    rec.dropped = sim["dropped"]
-                if t in eval_rounds and t == ts[-1]:
-                    rec.eval_metric = float(self.eval_fn(self.params, eval_data))
-                self.history.append(rec)
+            with jax.profiler.TraceAnnotation("fl.round", bucket=bucket,
+                                              rounds=seg_len):
+                with jax.profiler.TraceAnnotation("fl.launch"):
+                    metrics, compile_s, t0 = self._launch_segment(
+                        bucket, ts, client_batches, n_samples)
+                with jax.profiler.TraceAnnotation("fl.wait"):
+                    jax.block_until_ready(self.params)
+                wall = time.perf_counter() - t0
+                with jax.profiler.TraceAnnotation("fl.readback"):
+                    self._record_segment(bucket, ts, metrics, wall,
+                                         compile_s, gamma, wire_bytes,
+                                         flops_per_client, eval_rounds,
+                                         eval_data)
             self._round = ts[-1]
         return self.history
+
+    def _launch_segment(self, bucket: int, ts: List[int], client_batches,
+                        n_samples):
+        """Dispatch one segment's round program; the new state is stored
+        on the server before the device finishes.  Returns the metrics
+        (device arrays), the compile seconds (0 on a cache hit) and the
+        clock reading just before the call."""
+        seg_len = len(ts)
+        subs = []
+        for _ in ts:
+            self._key, sub = jax.random.split(self._key)
+            subs.append(sub)
+        if seg_len > 1:
+            t_arg = jnp.asarray(ts, jnp.float32)
+            key_arg = jnp.stack(subs)
+        else:
+            t_arg = jnp.asarray(ts[0], jnp.float32)
+            key_arg = subs[0]
+        # Engine-wide state convention: (params, residuals[, drift]
+        # [, norms]) — optional slots appear only when the strategy
+        # carries that state, so historical programs are unchanged.
+        state = [self.params, self._residuals]
+        if self._uses_drift:
+            state.append(self._drift)
+        if self._adaptive:
+            state.append(self._norms)
+        args = (*state, client_batches, n_samples, t_arg, key_arg)
+        compiled, compile_s = self._get_compiled(bucket, seg_len, args)
+        t0 = time.perf_counter()
+        out = compiled(*args)
+        self.params, self._residuals = out[0], out[1]
+        i = 2
+        if self._uses_drift:
+            self._drift = out[i]
+            i += 1
+        if self._adaptive:
+            self._norms = out[i]
+            i += 1
+        return out[i], compile_s, t0
+
+    def _record_segment(self, bucket, ts, metrics, wall, compile_s, gamma,
+                        wire_bytes, flops_per_client, eval_rounds,
+                        eval_data):
+        """Pull one segment's metrics to the host and append a
+        ``RoundRecord`` per round (with the simulated-fleet clock and the
+        eval where they apply)."""
+        seg_len = len(ts)
+        num_sampled = np.atleast_1d(np.asarray(metrics["num_sampled"]))
+        mean_loss = np.atleast_1d(np.asarray(metrics["mean_loss"]))
+        quarantined = np.atleast_1d(np.asarray(metrics["quarantined"]))
+        adversarial = None
+        if "num_adversarial" in metrics:
+            adversarial = np.atleast_1d(
+                np.asarray(metrics["num_adversarial"]))
+        if self._traits is not None:
+            part_masks = np.atleast_2d(np.asarray(metrics["part_mask"]))
+            arrived_masks = np.atleast_2d(
+                np.asarray(metrics["arrived_mask"]))
+        for i, t in enumerate(ts):
+            m = float(num_sampled[i])
+            rec = RoundRecord(
+                round=t,
+                num_sampled=int(m),
+                mean_loss=float(mean_loss[i]),
+                transport_units=m * gamma,
+                transport_bytes=int(m) * wire_bytes,
+                wall_s=wall / seg_len,
+                compile_s=compile_s if i == 0 else 0.0,
+                cohort_size=bucket,
+                quarantined=int(quarantined[i]),
+                adversarial=(int(adversarial[i])
+                             if adversarial is not None else 0),
+            )
+            if self._traits is not None:
+                sim = simulate_round(self._traits, part_masks[i],
+                                     arrived_masks[i],
+                                     float(flops_per_client), wire_bytes)
+                rec.sim_round_s = sim["sim_round_s"]
+                rec.straggler_s = sim["straggler_s"]
+                rec.dropped = sim["dropped"]
+            if t in eval_rounds and t == ts[-1]:
+                rec.eval_metric = float(self.eval_fn(self.params, eval_data))
+            self.history.append(rec)
 
     def _run_async(self, client_batches, n_samples, rounds, eval_rounds,
                    eval_data, gamma, wire_bytes, flops_per_client):
@@ -497,7 +529,6 @@ class FederatedServer:
                 wall_s=wall,
                 compile_s=stats["compile_s"],
                 cohort_size=bucket,
-                flop_proxy=float(flops_per_client) * bucket,
                 sim_round_s=stats["sim_round_s"],
                 straggler_s=stats["straggler_s"],
                 dropped=stats["dropped"],
@@ -620,7 +651,6 @@ class FederatedServer:
                 wall_s=wall,
                 compile_s=compile_s,
                 cohort_size=bucket,
-                flop_proxy=float(flops_per_client) * bucket,
                 quarantined=int(np.asarray(metrics["quarantined"])),
                 adversarial=int(np.asarray(
                     metrics["num_adversarial"]))

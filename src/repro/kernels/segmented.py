@@ -173,6 +173,7 @@ def segmented_histogram(x2d: jax.Array, seg_ids: jax.Array,
         out_specs=pl.BlockSpec((num_segments, SEG_NBINS), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((num_segments, SEG_NBINS), jnp.int32),
         interpret=interpret,
+        name="fl_seg_hist",
     )(x2d, seg_ids)
 
 
@@ -226,6 +227,7 @@ def segmented_count(x2d: jax.Array, seg_ids: jax.Array,
         out_specs=pl.BlockSpec((S, C), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((S, C), jnp.int32),
         interpret=interpret,
+        name="fl_seg_count",
     )(x2d, seg_ids, taus.astype(jnp.float32))
 
 
@@ -275,6 +277,7 @@ def segmented_apply(x2d: jax.Array, seg_ids: jax.Array, taus: jax.Array,
             jax.ShapeDtypeStruct((S, 1), jnp.int32),
         ),
         interpret=interpret,
+        name="fl_seg_apply",
     )(x2d, seg_ids, taus.reshape(S, 1).astype(jnp.float32))
 
 
@@ -348,6 +351,7 @@ def segmented_stats(x2d: jax.Array, seg_ids: jax.Array,
             jax.ShapeDtypeStruct((1, num_segments), jnp.float32),
         ),
         interpret=interpret,
+        name="fl_seg_stats",
     )(x2d, seg_ids)
     return hist, amax.reshape(num_segments, 1)
 
@@ -450,6 +454,7 @@ def segmented_encode(x2d: jax.Array, seg_ids: jax.Array, taus: jax.Array,
             jax.ShapeDtypeStruct((S, 1), jnp.int32),
         ),
         interpret=interpret,
+        name="fl_seg_encode",
     )(x2d, seg_ids, taus.reshape(S, 1).astype(jnp.float32),
       scales.reshape(S, 1).astype(jnp.float32))
 

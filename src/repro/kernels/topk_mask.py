@@ -95,6 +95,7 @@ def exponent_histogram(x2d: jax.Array, *, interpret: bool) -> jax.Array:
         out_specs=pl.BlockSpec((QBINS, RBINS), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((QBINS, RBINS), jnp.int32),
         interpret=interpret,
+        name="fl_topk_hist",
     )(x2d)
     return hist.reshape(NBINS)                    # bin = 8q + r, row-major
 
@@ -130,6 +131,7 @@ def count_ge(x2d: jax.Array, tau: jax.Array, *, interpret: bool) -> jax.Array:
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         interpret=interpret,
+        name="fl_topk_count",
     )(x2d, tau.reshape(1, 1).astype(jnp.float32))
     return cnt[0, 0]
 
@@ -155,6 +157,7 @@ def apply_threshold(x2d: jax.Array, tau: jax.Array, *, interpret: bool) -> jax.A
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
         interpret=interpret,
+        name="fl_topk_apply",
     )(x2d, tau.reshape(1, 1).astype(jnp.float32))
 
 

@@ -185,8 +185,6 @@ def test_server_engines_match():
     assert all(b >= r.num_sampled for b, r in zip(coh, cohort.history))
     assert coh[-1] < M and all(a >= b for a, b in zip(coh, coh[1:]))
     assert all(b in sched.bucket_ladder(M) for b in coh)
-    # flop proxy tracks the executed cohort, not the registered population
-    assert cohort.history[-1].flop_proxy < full.history[-1].flop_proxy
 
     # compile_s is metered on bucket-change rounds only; wall_s elsewhere
     changes = [i for i in range(len(coh)) if i == 0 or coh[i] != coh[i - 1]]
