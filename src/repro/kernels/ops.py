@@ -38,7 +38,8 @@ from repro.kernels.ref import EXPO_MIN
 PyTree = Any
 
 __all__ = ["topk_mask", "topk_mask_pytree", "topk_encode_pytree",
-           "pytree_sweep_count", "wirepath_sweep_count",
+           "coo_compact_reference", "pytree_sweep_count",
+           "wirepath_sweep_count",
            "wirepath_bytes_moved", "masked_count"]
 
 
@@ -203,48 +204,47 @@ def topk_mask_pytree(tree: PyTree, gamma: float, *,
 _WIRE_FLOOR_TAU = float(2.0 ** (EXPO_MIN - 1))
 
 
-def _leaf_wire(flat_vals, flat_bm, ls, seg_index, gamma, wire, scales):
-    """Compact ONE packed leaf's fused-sweep outputs into its wire payload.
+def _leaf_slots(flat_bm, ls, k):
+    """One packed leaf's keep-bits, capped at its k-slot budget, and each
+    entry's destination slot.
 
-    Reads the (already int8/bitmap-width) ``segmented_encode`` outputs only:
-    expands the leaf's keep-bits, assigns each surviving entry its
-    index-order slot via a cumulative sum (overflow beyond the k-slot budget
-    is shed by highest index — the jnp oracle sheds smallest magnitude
-    instead, an observable difference only on tie plateaus that overflow
-    the budget), and scatters values/indices into the static k-slot wire
-    arrays.  No sort, no re-read of fp32 data.
+    Slots follow index order via a cumulative sum of the LSB-first bits at
+    the leaf's offset; overflow beyond the k slots is shed by highest index
+    (the jnp oracle sheds smallest magnitude instead, an observable
+    difference only on tie plateaus that overflow the budget) and sent to
+    trash slot k.  Returns ``(live, dest)``.
     """
-    size = ls.size
-    k = min(max(1, int(round(gamma * size))), size)
-    v = jax.lax.slice_in_dim(flat_vals, ls.offset, ls.offset + size)
     byte0 = ls.offset // 8                       # offset is a SEG_LANE multiple
-    nb = (size + 7) // 8
-    bb = jax.lax.slice_in_dim(flat_bm, byte0, byte0 + nb)
-    bits = pk.unpack_bits(bb, size).astype(bool)  # LSB-first
-
-    slot = jnp.cumsum(bits) - 1                  # index-order slot per entry
+    bb = jax.lax.slice_in_dim(flat_bm, byte0, byte0 + (ls.size + 7) // 8)
+    bits = pk.unpack_bits(bb, ls.size).astype(bool)
+    slot = jnp.cumsum(bits) - 1
     live = bits & (slot < k)
-    dest = jnp.where(live, slot, k)              # overflow -> trash slot k
-    # int8 values scatter as int32: the TPU compiler takes ~5x longer on a
-    # sub-word scatter at embedding widths.
-    val_buf = jnp.zeros((k + 1,), jnp.int32 if v.dtype == jnp.int8
-                        else v.dtype).at[dest].set(
-        jnp.where(live, v, jnp.zeros_like(v))).astype(v.dtype)
-    if scales is not None:
-        values = {"q": val_buf[:k],
-                  "scale": scales[seg_index].astype(jnp.float32)}
-    else:
-        values = val_buf[:k].astype(ls.dtype)
-    shape = np.asarray(ls.shape, np.int32)
+    return live, jnp.where(live, slot, k)
 
-    if wire == "coo":
-        idx_buf = jnp.zeros((k + 1,), jnp.int32).at[dest].set(
-            jnp.where(live, jnp.arange(size, dtype=jnp.int32), 0))
-        return {"indices": idx_buf[:k], "values": values, "shape": shape}
-    # bitmap wire: repack the budget-capped bits so the popcount can never
-    # exceed the value slots (byte-identical to compression.encode_bitmap).
-    bm = pk.pack_bits(live)
-    return {"bitmap": bm, "values": values, "shape": shape}
+
+def _scatter_slots(x, live, dest, k):
+    """Scatter ``x``'s live entries into k static slots, zeros elsewhere.
+    int8 scatters as int32: the TPU compiler takes ~5x longer on a sub-word
+    scatter at embedding widths."""
+    wide = jnp.int32 if x.dtype == jnp.int8 else x.dtype
+    buf = jnp.zeros((k + 1,), wide).at[dest].set(
+        jnp.where(live, x, jnp.zeros_like(x)).astype(wide))
+    return buf[:k].astype(x.dtype)
+
+
+def coo_compact_reference(vals, bitmap, spec, budgets):
+    """Cumsum + scatter COO compaction: the reference that
+    ``seg.segmented_compact`` (same arguments, same result) matches byte for
+    byte.  Per segment ``(indices, values)``, (k,) each."""
+    flat_v, flat_bm = vals.reshape(-1), bitmap.reshape(-1)
+    out = []
+    for ls, k in zip(spec.leaves, budgets):
+        live, dest = _leaf_slots(flat_bm, ls, k)
+        v = jax.lax.slice_in_dim(flat_v, ls.offset, ls.offset + ls.size)
+        index = jnp.arange(ls.size, dtype=jnp.int32)
+        out.append((_scatter_slots(index, live, dest, k),
+                    _scatter_slots(v, live, dest, k)))
+    return out
 
 
 def topk_encode_pytree(tree: PyTree, gamma: float, *,
@@ -262,10 +262,10 @@ def topk_encode_pytree(tree: PyTree, gamma: float, *,
     instead of materialising a masked dense pytree for ``core.codecs`` to
     re-read three more times, the final segmented sweep
     (``seg.segmented_encode``) emits int8-quantised values, a 1-bit/element
-    keep-bitmap and kept counts directly, and the per-leaf compaction into
-    static ``k = max(1, round(gamma * size))``-slot payloads reads only
-    those narrow outputs.  HBM cost: ``wirepath_sweep_count`` /
-    ``wirepath_bytes_moved``.
+    keep-bitmap and kept counts directly, and the compaction into static
+    ``k = max(1, round(gamma * size))``-slot payloads reads only those
+    narrow outputs (for COO, one ``seg.segmented_compact`` kernel over every
+    leaf).  HBM cost: ``wirepath_sweep_count`` / ``wirepath_bytes_moved``.
 
     Per maskable leaf (``size >= min_leaf_size``) the returned pytree holds
 
@@ -345,10 +345,32 @@ def topk_encode_pytree(tree: PyTree, gamma: float, *,
 
     out2d, bm2d, _kept = seg.segmented_encode(
         x2d, seg_ids, tau, scales, interpret=interpret, slab_rows=slab_rows)
-    flat_vals = out2d[:spec.rows].reshape(-1)
-    flat_bm = bm2d[:spec.rows].reshape(-1)
-    for s, (i, ls) in enumerate(zip(mask_idx, spec.leaves)):
-        leaves[i] = _leaf_wire(flat_vals, flat_bm, ls, s, gamma, wire, scales)
+    budgets = [min(max(1, int(round(gamma * ls.size))), ls.size)
+               for ls in spec.leaves]
+    if wire == "coo":
+        coo = seg.segmented_compact(out2d, bm2d, spec, budgets,
+                                    interpret=interpret, slab_rows=slab_rows)
+    flat_vals, flat_bm = out2d.reshape(-1), bm2d.reshape(-1)
+    for s, (i, ls, k) in enumerate(zip(mask_idx, spec.leaves, budgets)):
+        if wire == "coo":
+            payload = {"indices": coo[s][0]}
+            values = coo[s][1]
+        else:
+            # bitmap wire: repack the budget-capped bits so the popcount
+            # can never exceed the value slots (byte-identical to
+            # compression.encode_bitmap).
+            live, dest = _leaf_slots(flat_bm, ls, k)
+            v = jax.lax.slice_in_dim(flat_vals, ls.offset,
+                                     ls.offset + ls.size)
+            payload = {"bitmap": pk.pack_bits(live)}
+            values = _scatter_slots(v, live, dest, k)
+        if scales is not None:
+            payload["values"] = {"q": values,
+                                 "scale": scales[s].astype(jnp.float32)}
+        else:
+            payload["values"] = values.astype(ls.dtype)
+        payload["shape"] = np.asarray(ls.shape, np.int32)
+        leaves[i] = payload
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 

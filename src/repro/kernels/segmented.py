@@ -29,6 +29,10 @@ sweeps:
    element keep-bitmap, and kept counts, all emitted from ONE read of the
    packed buffer.  ``ops.topk_encode_pytree`` compacts the outputs into
    COO / bitmap payloads without ever re-reading the fp32 data.
+6. ``segmented_compact``    — the COO compaction of those outputs: each
+   row packed by a shift network in VMEM and appended at its running
+   slot, full 1024-slot blocks written by aligned DMAs — no sort, no
+   scatter, one launch for every leaf of a vmapped cohort.
 
 Grid/tiling: each grid step processes a ``(slab_rows, SEG_LANE)`` slab.  The
 per-row segment ids ride along as an (R, 1) int32 input; inside the kernel
@@ -55,7 +59,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import NBINS, EXPO_MIN
 from repro.kernels.packing import SEG_LANE
@@ -68,6 +74,7 @@ __all__ = [
     "segmented_apply",
     "segmented_stats",
     "segmented_encode",
+    "segmented_compact",
     "select_thresholds",
     "candidate_taus",
     "shrink_brackets",
@@ -457,6 +464,311 @@ def segmented_encode(x2d: jax.Array, seg_ids: jax.Array, taus: jax.Array,
         name="fl_seg_encode",
     )(x2d, seg_ids, taus.reshape(S, 1).astype(jnp.float32),
       scales.reshape(S, 1).astype(jnp.float32))
+
+
+# --------------------------------------------------------------------------
+# Kernel 6: COO stream compaction of the fused encode's outputs — no sort
+# and no scatter (DESIGN.md §10).
+# --------------------------------------------------------------------------
+# The output is written in groups of eight SEG_LANE-slot blocks, one
+# aligned (8, SEG_LANE) tile per DMA, so each segment's slot region is
+# padded to whole groups.
+COMPACT_GROUP = 8 * SEG_LANE
+# Rows per drop-count matmul: the (rows, SEG_LANE) x (SEG_LANE, SEG_LANE)
+# triangular product fills the MXU's 128 rows.
+_COMPACT_MM_ROWS = 128
+# Per-row scalars: first output slot, kept count, segment region end, and
+# whether the row is its segment's last.
+_META = 4
+_LOG_LANE = SEG_LANE.bit_length() - 1
+
+
+def _compact_rows(v: jax.Array, t: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Left-pack each row's kept entries with a log2(SEG_LANE)-step shift
+    network.
+
+    ``t`` holds each kept entry's count of dropped entries before it in its
+    row (-1 where dropped).  Step b moves the entries whose count has bit b
+    set left by 2^b; taking the bits low to high keeps the entries distinct
+    and in order at every step, so no two ever meet.  Returns the moved
+    values and counts: kept entry j lands at lane j - t, still carrying t.
+    """
+    for b in range(_LOG_LANE):
+        sh = 1 << b
+        inc_t = pltpu.roll(t, SEG_LANE - sh, 1)          # lane p <- p + 2^b
+        inc_v = pltpu.roll(v, SEG_LANE - sh, 1)
+        take = (inc_t >= 0) & ((inc_t & sh) != 0)
+        stay = (t >= 0) & ((t & sh) == 0)
+        t = jnp.where(take, inc_t, jnp.where(stay, t, -1))
+        v = jnp.where(take, inc_v, v)
+    return v, t
+
+
+def _seg_compact_kernel(meta_ref, val_ref, bm_ref, base_ref, expand_ref,
+                        tri_ref, _zeros_v, _zeros_i, out_v, out_i,
+                        tag_ref, rows_ref, open_ref, group_ref, state, sems,
+                        *, mm_rows):
+    c, i = pl.program_id(0), pl.program_id(1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, SEG_LANE), 1)
+    outs = (out_v, out_i)
+
+    @pl.when((c == 0) & (i == 0))
+    def _init():
+        state[0] = 0                       # staging slot being filled
+        state[1] = 0                       # slot 0 has a DMA in flight
+        state[2] = 0                       # slot 1 has a DMA in flight
+        open_ref[...] = jnp.zeros_like(open_ref)
+
+    def dma(slot, kind, grp):
+        return pltpu.make_async_copy(
+            group_ref.at[slot, kind], outs[kind].at[c, pl.ds(grp * 8, 8)],
+            sems.at[slot, kind])
+
+    def wait(slot):
+        for kind in range(2):
+            dma(slot, kind, 0).wait()
+        state[1 + slot] = 0
+
+    def flush(grp, end):
+        # Groups at or past the region's end hold slots beyond the budget:
+        # shed, never written.
+        @pl.when(grp * COMPACT_GROUP < end)
+        def _():
+            slot = state[0]
+            for kind in range(2):
+                dma(slot, kind, grp).start()
+            state[1 + slot] = 1
+            other = 1 - slot
+
+            @pl.when(state[1 + other] == 1)
+            def _():
+                wait(other)
+            state[0] = other
+
+    def put(av, ai, blk, end):
+        q = blk & 7
+        slot = state[0]
+        group_ref[slot, 0, pl.ds(q, 1), :] = av
+        group_ref[slot, 1, pl.ds(q, 1), :] = ai
+
+        @pl.when(q == 7)
+        def _():
+            flush(blk >> 3, end)
+
+    def seal(av, ai, e, end):
+        """Write out the segment's last, partly filled group."""
+        blk, rem = e >> _LOG_LANE, e & (SEG_LANE - 1)
+
+        @pl.when(rem != 0)
+        def _():
+            put(av, ai, blk, end)
+        filled = (blk & 7) + (rem != 0).astype(jnp.int32)
+
+        @pl.when((filled > 0) & (filled < 8))
+        def _():
+            slot = state[0]
+            sub = jax.lax.broadcasted_iota(jnp.int32, (8, SEG_LANE), 0)
+            for kind in range(2):
+                group_ref[slot, kind] = jnp.where(
+                    sub < filled, group_ref[slot, kind], 0)
+            flush(blk >> 3, end)
+
+    def merge(r, vrow, irow, carry):
+        """Append row ``r``'s packed entries at its first output slot."""
+        av, ai = carry
+        s, n = meta_ref[0, _META * r], meta_ref[0, _META * r + 1]
+        end, last = meta_ref[0, _META * r + 2], meta_ref[0, _META * r + 3]
+        off = s & (SEG_LANE - 1)
+        rv, ri = pltpu.roll(vrow, off, 1), pltpu.roll(irow, off, 1)
+        rel = lane - off
+        into_open = (rel >= 0) & (rel < n)
+        into_next = (rel < 0) & (rel + SEG_LANE < n)
+        av, ai = jnp.where(into_open, rv, av), jnp.where(into_open, ri, ai)
+        full = off + n >= SEG_LANE
+
+        @pl.when(full)
+        def _():
+            put(av, ai, s >> _LOG_LANE, end)
+        av = jnp.where(full, jnp.where(into_next, rv, 0), av)
+        ai = jnp.where(full, jnp.where(into_next, ri, 0), ai)
+
+        @pl.when(last == 1)
+        def _():
+            seal(av, ai, s + n, end)
+        done = last == 1
+        return jnp.where(done, 0, av), jnp.where(done, 0, ai)
+
+    def mm_chunk(m, carry):
+        r0 = pl.multiple_of(m * mm_rows, mm_rows)
+        # keep bits per lane: each bitmap byte spread over its 8 lanes
+        byte = bm_ref[pl.ds(r0, mm_rows), :].astype(jnp.int32)
+        lane_byte = jax.lax.dot(
+            byte.astype(jnp.float32).astype(jnp.bfloat16), expand_ref[...],
+            preferred_element_type=jnp.float32).astype(jnp.int32)
+        mm_lane = jax.lax.broadcasted_iota(jnp.int32, (mm_rows, SEG_LANE), 1)
+        keep = (lane_byte >> (mm_lane & 7)) & 1
+        # dropped entries before each lane: 0/1 products, exact in f32
+        drops = jax.lax.dot(
+            (1 - keep).astype(jnp.bfloat16), tri_ref[...],
+            preferred_element_type=jnp.float32).astype(jnp.int32)
+        tag_ref[...] = jnp.where(keep == 1, drops, -1)
+
+        def rows8(j, carry):
+            rr = pl.multiple_of(j * 8, 8)
+            v, t = _compact_rows(val_ref[pl.ds(r0 + rr, 8), :],
+                                 tag_ref[pl.ds(rr, 8), :])
+            kept = t >= 0
+            rows_ref[0] = jnp.where(kept, v, 0)
+            rows_ref[1] = jnp.where(
+                kept, base_ref[pl.ds(r0 + rr, 8), :] + lane + t, 0)
+
+            def row(q, carry):
+                return merge(r0 + rr + q, rows_ref[0, pl.ds(q, 1), :],
+                             rows_ref[1, pl.ds(q, 1), :], carry)
+
+            return jax.lax.fori_loop(0, 8, row, carry)
+
+        return jax.lax.fori_loop(0, mm_rows // 8, rows8, carry)
+
+    carry = jax.lax.fori_loop(0, val_ref.shape[0] // mm_rows, mm_chunk,
+                              (open_ref[0:1, :], open_ref[1:2, :]))
+    open_ref[0:1, :], open_ref[1:2, :] = carry
+
+    @pl.when((c == pl.num_programs(0) - 1) & (i == pl.num_programs(1) - 1))
+    def _drain():
+        for slot in range(2):
+            @pl.when(state[1 + slot] == 1)
+            def _():
+                wait(slot)
+
+
+def _fold_vmap(call):
+    """Wrap ``call`` (arrays with a leading client axis -> tuple of such
+    arrays) so that ``jax.vmap`` folds the mapped axis into the client axis:
+    a vmapped cohort becomes ONE kernel launch with a client grid axis."""
+    @jax.custom_batching.custom_vmap
+    def fn(*xs):
+        return call(*xs)
+
+    @fn.def_vmap
+    def _rule(axis_size, in_batched, *xs):
+        xs = [x if b else jnp.broadcast_to(x, (axis_size, *x.shape))
+              for x, b in zip(xs, in_batched)]
+        outs = fn(*[x.reshape(-1, *x.shape[2:]) for x in xs])
+        outs = tuple(o.reshape(axis_size, -1, *o.shape[1:]) for o in outs)
+        return outs, (True,) * len(outs)
+
+    return fn
+
+
+def _compact_call(meta, vals, bitmap, *, lane_base, num_blocks, slab,
+                  interpret):
+    B, R, L = vals.shape
+    mm_rows = _COMPACT_MM_ROWS if slab % _COMPACT_MM_ROWS == 0 else CHUNK_ROWS
+    r = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    tri = (r < col).astype(jnp.bfloat16)                  # strict upper
+    expand = (col[:L // 8] // 8 == r[:L // 8]).astype(jnp.bfloat16)
+    out_shape = jax.ShapeDtypeStruct((B, num_blocks, L), jnp.int32)
+    zeros = [jnp.zeros(out_shape.shape, jnp.int32) for _ in range(2)]
+    return pl.pallas_call(
+        functools.partial(_seg_compact_kernel, mm_rows=mm_rows),
+        grid=(B, R // slab),
+        in_specs=[
+            pl.BlockSpec((None, None, 1, _META * slab),
+                         lambda c, i: (c, i, 0, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, slab, L), lambda c, i: (c, i, 0)),
+            pl.BlockSpec((None, slab, L // 8), lambda c, i: (c, i, 0)),
+            pl.BlockSpec((slab, 1), lambda c, i: (i, 0)),
+            pl.BlockSpec((L // 8, L), lambda c, i: (0, 0)),
+            pl.BlockSpec((L, L), lambda c, i: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),) * 2,
+        out_shape=(out_shape, out_shape),
+        scratch_shapes=[
+            pltpu.VMEM((mm_rows, L), jnp.int32),          # drop counts
+            pltpu.VMEM((2, 8, L), jnp.int32),             # packed rows
+            pltpu.VMEM((2, L), jnp.int32),                # open block
+            pltpu.VMEM((2, 2, 8, L), jnp.int32),          # group staging
+            pltpu.SMEM((3,), jnp.int32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+        input_output_aliases={6: 0, 7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="fl_seg_compact",
+    )(meta.reshape(B, R // slab, 1, _META * slab), vals, bitmap, lane_base,
+      expand, tri, *zeros)
+
+
+def segmented_compact(vals: jax.Array, bitmap: jax.Array, spec,
+                      budgets, *, interpret: bool,
+                      slab_rows: int | None = None
+                      ) -> list[tuple[jax.Array, jax.Array]]:
+    """Stream-compact the fused encode's outputs into per-segment COO slots.
+
+    ``vals``: (R, SEG_LANE) f32 or int8 values and ``bitmap``:
+    (R, SEG_LANE // 8) uint8 keep-bits, both as :func:`segmented_encode`
+    wrote them over ``spec``'s packed rows (R a slab multiple).
+    ``budgets``: static slot count k per segment.
+
+    Returns, per segment, ``(indices, values)``: (k,) int32 arrays holding
+    the kept entries in index order (index = position in the leaf), the
+    first k kept where more were kept (shed by highest index), zeros in
+    the slots nothing filled: exactly the cumsum + scatter reference
+    ``ops.coo_compact_reference``.
+
+    Each SEG_LANE row is packed by a shift network in VMEM; a row's first
+    output slot comes from a per-segment running count of kept entries,
+    computed ahead on the (R,) row popcounts.  The packed row is rotated to
+    that slot's lane and merged into the open block; full blocks gather in
+    an (8, SEG_LANE) staging tile that goes to HBM by one aligned DMA.
+    Values cross the kernel as 32-bit words: f32 bit patterns, or int8
+    widened to int32 and narrowed after.  Under ``jax.vmap`` the mapped
+    axis becomes the kernel's client grid axis.
+    """
+    narrow = vals.dtype == jnp.int8
+    words = (vals.astype(jnp.int32) if narrow
+             else jax.lax.bitcast_convert_type(vals, jnp.int32))
+    R = vals.shape[0]
+    slab = _slab(R, slab_rows, interpret)
+    seg = spec.seg_ids()[:, 0]
+    regions = [-(-k // COMPACT_GROUP) * COMPACT_GROUP for k in budgets]
+    region_base = np.cumsum([0] + regions)
+    first_row = np.asarray([ls.offset // SEG_LANE for ls in spec.leaves])
+    last_row = first_row + np.asarray([ls.num_rows for ls in spec.leaves]) - 1
+    static = np.zeros((R, _META), np.int32)       # padding rows: no-ops
+    static[:spec.rows, 0] = region_base[seg]
+    static[:spec.rows, 2] = region_base[seg + 1]
+    static[last_row, 3] = 1
+    lane_base = np.zeros((R, 1), np.int32)
+    lane_base[:spec.rows, 0] = (np.arange(spec.rows)
+                                - first_row[seg]) * SEG_LANE
+    seg_first = np.zeros((R,), np.int32)
+    seg_first[:spec.rows] = first_row[seg]
+
+    kept = jnp.sum(jax.lax.population_count(bitmap).astype(jnp.int32),
+                   axis=1)
+    before = jnp.cumsum(kept) - kept
+    zero = jnp.zeros_like(kept)
+    dyn = jnp.stack([before - before[seg_first], kept, zero, zero], axis=1)
+    meta = (dyn + static).reshape(-1)
+
+    call = functools.partial(
+        _compact_call, lane_base=jnp.asarray(lane_base),
+        num_blocks=int(region_base[-1]) // SEG_LANE, slab=slab,
+        interpret=interpret)
+    out_v, out_i = _fold_vmap(call)(meta[None], words[None], bitmap[None])
+    flat_v = out_v[0].reshape(-1)
+    flat_v = (flat_v.astype(jnp.int8) if narrow
+              else jax.lax.bitcast_convert_type(flat_v, vals.dtype))
+    flat_i = out_i[0].reshape(-1)
+    return [(jax.lax.slice_in_dim(flat_i, b, b + k),
+             jax.lax.slice_in_dim(flat_v, b, b + k))
+            for b, k in zip(region_base[:-1].tolist(), budgets)]
 
 
 # --------------------------------------------------------------------------

@@ -16,6 +16,7 @@ worker imports this file.
 """
 
 import jax
+import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -92,6 +93,11 @@ def test_topk_mask_pytree_compiles_for_v5e_under_vmap(one_chip):
     assert n >= 1
 
 
+# The COO wire's compaction stage (seg.segmented_compact): one kernel
+# launch for every COO leaf of the tree, on top of the HBM sweeps.
+COMPACT_STAGE = 1
+
+
 @pytest.mark.parametrize("wire", ["coo", "bitmap"])
 @pytest.mark.parametrize("quantize", [False, True], ids=["f32", "int8"])
 def test_topk_encode_pytree_compiles_for_v5e(delta_tree, wire, quantize):
@@ -100,7 +106,26 @@ def test_topk_encode_pytree_compiles_for_v5e(delta_tree, wire, quantize):
                                          quantize=quantize, interpret=False),
         delta_tree)
     assert n >= 1
-    assert n == ops.wirepath_sweep_count(fused=True, quantize=quantize)
+    compact = COMPACT_STAGE if wire == "coo" else 0
+    assert n == ops.wirepath_sweep_count(fused=True,
+                                         quantize=quantize) + compact
+
+
+@pytest.mark.parametrize("quantize", [False, True], ids=["f32", "int8"])
+def test_coo_compaction_compiles_for_v5e_under_cohort_vmap(one_chip,
+                                                           quantize):
+    """The codec's position in the round: the GRU embedding's COO wire at
+    gamma 0.5 for a 32-client cohort, compacted by one kernel launch with
+    no sort left in the program."""
+    emb = jax.ShapeDtypeStruct((32, WIKITEXT2_VOCAB, 128), jnp.float32,
+                               sharding=one_chip)
+    text = jax.jit(jax.vmap(lambda t: ops.topk_encode_pytree(
+        {"embed": t}, 0.5, quantize=quantize, assume_masked=True,
+        interpret=False))).lower(emb).compile().as_text()
+    assert text.count("tpu_custom_call") == ops.wirepath_sweep_count(
+        fused=True, assume_masked=True, quantize=quantize) + COMPACT_STAGE
+    assert "fl_seg_compact" in text
+    assert " sort(" not in text
 
 
 @pytest.mark.parametrize("leaf", [("gru", "embed"), ("vgg", "conv3b", "w")],
