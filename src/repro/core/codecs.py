@@ -25,7 +25,9 @@ format.  Earlier revisions only *estimated* upload bytes
   codecs above cost.  Wire layout, bytes and decoded values are identical
   to the equivalent jnp codec (``SparseCodec`` / ``BitmapCodec`` /
   ``ChainCodec(..., Int8Codec())``) — the jnp codecs stay verbatim as the
-  bit-exactness oracle, and ``decode`` simply delegates to them.
+  bit-exactness oracle.  ``decode`` of the COO wire is one Pallas expand
+  launch, the inverse of the encode's compaction; the bitmap wire
+  decodes through the oracle.
 
 Every codec reports **exact** wire bytes: ``wire_bytes(tree)`` traces
 ``encode`` with ``jax.eval_shape`` (no FLOPs, no device buffers) and sums
@@ -50,7 +52,7 @@ import numpy as np
 from repro.core.compression import (_is_concrete, decode_bitmap,
                                     decode_sparse, dequantize_int8,
                                     encode_bitmap, encode_sparse,
-                                    quantize_int8)
+                                    quantize_int8, sparse_parts)
 
 PyTree = Any
 
@@ -242,6 +244,23 @@ class Int8Codec(UploadCodec):
             wire, is_leaf=_is_q8)
 
 
+def _check_ascending(indices: Any, values: Any) -> None:
+    """The fused COO wire's layout, checked on a concrete payload: the
+    live slots (slot 0, and every slot whose index is above 0) come
+    first with strictly ascending indices, then (0, 0) pads."""
+    if not (_is_concrete(indices) and _is_concrete(values)):
+        return
+    idx, val = np.asarray(indices), np.asarray(values)
+    live = idx > 0
+    live[:1] = True
+    n = int(live.sum())
+    if (not live[:n].all() or (np.diff(idx[:n]) <= 0).any()
+            or (val[n:] != 0).any()):
+        raise ValueError(
+            "fused COO payload: live indices must be strictly ascending "
+            "and followed only by (0, 0) pads")
+
+
 def _is_bitmap(leaf: Any) -> bool:
     return isinstance(leaf, dict) and "bitmap" in leaf and "values" in leaf
 
@@ -304,12 +323,23 @@ class FusedSparseCodec(UploadCodec):
 
     The wire is structurally and byte-identical to the equivalent jnp
     codec — ``SparseCodec`` / ``BitmapCodec``, chained with
-    :class:`Int8Codec` when ``quantized`` — and ``decode`` delegates to
-    those oracles, inheriting their malformed-payload validation.  Decoded
-    values are bit-exact vs the oracle whenever each leaf's nonzero count
-    fits its slot budget (threshold masks guarantee this off tie
-    plateaus); on an overflowing plateau the fused path sheds by highest
-    index where the oracle sheds smallest magnitude.
+    :class:`Int8Codec` when ``quantized``.  Decoded values are bit-exact
+    vs the oracle whenever each leaf's nonzero count fits its slot budget
+    (threshold masks guarantee this off tie plateaus); on an overflowing
+    plateau the fused path sheds by highest index where the oracle sheds
+    smallest magnitude.
+
+    ``decode`` of the COO wire is the inverse of the encode's compaction:
+    one ``seg.segmented_expand`` launch writes every COO leaf's dense
+    tensor, bit-identical to the oracle's ``decode_sparse`` (int8 values
+    are dequantised first by the oracle's own ``dequantize_int8``).  It
+    needs the layout the compaction writes: each leaf's live slots (slot
+    0, and every slot whose index is above 0) first, with indices
+    strictly ascending, then (0, 0) pads.  Inside a round the wire comes
+    from the same ``roundtrip``'s encode, so that holds by construction;
+    a concrete wire is checked, and one out of that layout raises
+    ``ValueError``, as do the oracle's malformed-payload checks.  The
+    bitmap wire decodes through the oracle.
 
     ``interpret=None`` picks by backend: compiled on a TPU, the Pallas
     interpreter elsewhere (the CPU tests).
@@ -369,8 +399,33 @@ class FusedSparseCodec(UploadCodec):
         return jax.tree_util.tree_map(small, wire, is_leaf=payload)
 
     def decode(self, wire: PyTree) -> PyTree:
-        """Delegate to the jnp oracle codec (same wire, same validation)."""
-        return self._oracle().decode(wire)
+        """Expand every COO leaf in one kernel launch (bitmap wire: the
+        jnp oracle codec); dense leaves pass the non-finite gate."""
+        from repro.kernels import ops
+        from repro.kernels import packing as pk
+        from repro.kernels import segmented as seg
+
+        if self.wire == "bitmap":
+            return self._oracle().decode(wire)
+        if self.quantized:
+            wire = Int8Codec().decode(wire)
+        leaves, treedef = jax.tree_util.tree_flatten(wire, is_leaf=_is_coo)
+        at = [i for i, leaf in enumerate(leaves) if _is_coo(leaf)]
+        coo, shapes = [], []
+        for i in at:
+            indices, values, shape = sparse_parts(leaves[i])
+            _check_ascending(indices, values)
+            coo.append((indices, values))
+            shapes.append(jax.ShapeDtypeStruct(shape, values.dtype))
+        leaves = [leaf if _is_coo(leaf) else _reject_nonfinite(leaf, self.name)
+                  for leaf in leaves]
+        if at:
+            dense = seg.segmented_expand(
+                coo, pk.build_pack_spec(shapes),
+                interpret=ops._auto_interpret(self.interpret))
+            for i, leaf in zip(at, dense):
+                leaves[i] = leaf
+        return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -35,6 +35,7 @@ __all__ = [
     "pytree_num_params",
     "pytree_payload_bytes",
     "encode_sparse",
+    "sparse_parts",
     "decode_sparse",
     "encode_bitmap",
     "decode_bitmap",
@@ -172,8 +173,8 @@ def _as_array(x: Any, name: str):
     return arr
 
 
-def decode_sparse(payload: Dict[str, jax.Array]) -> jax.Array:
-    """Decode a COO payload back to a dense tensor.
+def sparse_parts(payload: Dict[str, jax.Array]):
+    """Validate a COO payload; returns ``(indices, values, shape)``.
 
     Malformed payloads fail loudly instead of silently scatter-adding
     garbage: missing keys, index/value length mismatch, non-integer
@@ -214,6 +215,14 @@ def decode_sparse(payload: Dict[str, jax.Array]) -> jax.Array:
                 and not np.isfinite(v).all()):
             raise ValueError(
                 "sparse payload values contain non-finite entries")
+    return indices, values, shape
+
+
+def decode_sparse(payload: Dict[str, jax.Array]) -> jax.Array:
+    """Decode a COO payload back to a dense tensor: every slot added into
+    a zeroed tensor, after :func:`sparse_parts`' validation."""
+    indices, values, shape = sparse_parts(payload)
+    size = int(np.prod(shape, dtype=np.int64)) if shape else 1
     out = jnp.zeros((size,), values.dtype)
     out = out.at[indices].add(values)
     return out.reshape(shape)

@@ -75,6 +75,7 @@ __all__ = [
     "segmented_stats",
     "segmented_encode",
     "segmented_compact",
+    "segmented_expand",
     "select_thresholds",
     "candidate_taus",
     "shrink_brackets",
@@ -769,6 +770,203 @@ def segmented_compact(vals: jax.Array, bitmap: jax.Array, spec,
     return [(jax.lax.slice_in_dim(flat_i, b, b + k),
              jax.lax.slice_in_dim(flat_v, b, b + k))
             for b, k in zip(region_base[:-1].tolist(), budgets)]
+
+
+# --------------------------------------------------------------------------
+# Kernel 7: COO expand — the inverse of the compaction: each leaf's
+# (indices, values) slots back to its dense tensor, with no sort and no
+# scatter (DESIGN.md §10).
+# --------------------------------------------------------------------------
+# The expand's rows are one vreg wide: Mosaic lowers a slice of a VMEM
+# window at a dynamic, unaligned row offset only for 128-lane rows, and a
+# leaf whose last axis is 128 then comes out of the buffer as it is laid
+# out.  A group is one COMPACT_GROUP-element output tile.
+EXPAND_LANE = 128
+_LOG_EXPAND_LANE = EXPAND_LANE.bit_length() - 1
+_EXPAND_ROWS = COMPACT_GROUP // EXPAND_LANE
+_LOG_GROUP = COMPACT_GROUP.bit_length() - 1
+# A group's slots span at most _EXPAND_ROWS + 1 slot rows from a start
+# row that the window's 8-aligned first row puts within its first 8.
+_EXPAND_WIN = _EXPAND_ROWS + 8
+
+
+def _flat_shift(x: jax.Array, s: int) -> jax.Array:
+    """Move every entry of an (rows, EXPAND_LANE) tile ``s`` places on in
+    its row-major order, row ends spilling into the next row."""
+    if s >= EXPAND_LANE:
+        return pltpu.roll(x, s // EXPAND_LANE, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    y = pltpu.roll(x, s, 1)
+    return jnp.where(lane >= s, y, pltpu.roll(y, 1, 0))
+
+
+def _expand_tile(v: jax.Array, t: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Spread a group's run of slots to their places: the mirror of
+    :func:`_compact_rows`.
+
+    ``t`` holds each live entry's distance to its place, its index less
+    its rank in the run (-1 where dead).  Indices ascend, so ``t`` never
+    falls along the run.  Step b moves the entries whose distance has bit
+    b set on by 2^b; taking the bits high to low keeps the entries
+    distinct and in order at every step, and none passes the tile's end.
+    Returns the moved values and distances: entry m lands at m + t.
+    """
+    for b in reversed(range(_LOG_GROUP)):
+        sh = 1 << b
+        inc_t, inc_v = _flat_shift(t, sh), _flat_shift(v, sh)
+        take = (inc_t >= 0) & ((inc_t & sh) != 0)
+        stay = (t >= 0) & ((t & sh) == 0)
+        t = jnp.where(take, inc_t, jnp.where(stay, t, -1))
+        v = jnp.where(take, inc_v, v)
+    return v, t
+
+
+def _seg_expand_kernel(meta_ref, idx_hbm, val_hbm, out_ref, win_i, win_v,
+                       sems):
+    c = pl.program_id(0)
+    groups = out_ref.shape[0] // _EXPAND_ROWS
+    shape = (_EXPAND_ROWS, EXPAND_LANE)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    rank = jax.lax.broadcasted_iota(jnp.int32, shape, 0) * EXPAND_LANE + lane
+
+    def window(g, slot):
+        r0 = pl.multiple_of(meta_ref[0, _META * g], 8)
+        return [pltpu.make_async_copy(src.at[c, pl.ds(r0, _EXPAND_WIN)],
+                                      dst.at[slot], sems.at[slot, kind])
+                for kind, (src, dst) in enumerate(((idx_hbm, win_i),
+                                                   (val_hbm, win_v)))]
+
+    for copy in window(0, 0):
+        copy.start()
+
+    def group(g, carry):
+        slot = g & 1
+
+        @pl.when(g + 1 < groups)
+        def _():
+            for copy in window(g + 1, 1 - slot):
+                copy.start()
+        for copy in window(g, slot):
+            copy.wait()
+        u, n = meta_ref[0, _META * g + 1], meta_ref[0, _META * g + 2]
+        e0 = meta_ref[0, _META * g + 3]
+        q0, off = u >> _LOG_EXPAND_LANE, u & (EXPAND_LANE - 1)
+        rot = (EXPAND_LANE - off) & (EXPAND_LANE - 1)
+
+        def run(ref):
+            """The group's slots from window slot ``u`` on, at rank 0."""
+            a = pltpu.roll(ref[slot, pl.ds(q0, _EXPAND_ROWS), :], rot, 1)
+            b = pltpu.roll(ref[slot, pl.ds(q0 + 1, _EXPAND_ROWS), :], rot, 1)
+            return jnp.where(lane < EXPAND_LANE - off, a, b)
+
+        t = jnp.where(rank < n, run(win_i) - e0 - rank, -1)
+        v, t = _expand_tile(run(win_v), t)
+        # ±0 both land as +0, as the reference's add into zeros gives.
+        row0 = pl.multiple_of(g * _EXPAND_ROWS, _EXPAND_ROWS)
+        out_ref[pl.ds(row0, _EXPAND_ROWS), :] = jnp.where(
+            (t >= 0) & (v != 0), v, 0.0)
+        return carry
+
+    jax.lax.fori_loop(0, groups, group, 0)
+
+
+def _expand_call(meta, idx, vals, *, rows, slab, interpret):
+    B = idx.shape[0]
+    return (pl.pallas_call(
+        _seg_expand_kernel,
+        grid=(B, rows // slab),
+        in_specs=[
+            pl.BlockSpec((None, None, 1, _META * slab // _EXPAND_ROWS),
+                         lambda c, i: (c, i, 0, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((None, slab, EXPAND_LANE),
+                               lambda c, i: (c, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, rows, EXPAND_LANE), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((2, _EXPAND_WIN, EXPAND_LANE), jnp.int32),
+            pltpu.VMEM((2, _EXPAND_WIN, EXPAND_LANE), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="fl_seg_expand",
+    )(meta.reshape(B, rows // slab, 1, -1), idx, vals),)
+
+
+def segmented_expand(coo, spec, *, interpret: bool,
+                     slab_rows: int | None = None) -> list[jax.Array]:
+    """Expand each leaf's COO slots back to its dense tensor: the inverse
+    of :func:`segmented_compact`.
+
+    ``coo``: per leaf of ``spec``, ``(indices, values)`` (k,) arrays laid
+    out as the compaction writes them: the live slots (slot 0, and every
+    slot whose index is above 0) first, indices strictly ascending, then
+    (0, 0) pads.  Returns the dense leaves, ``spec``'s shapes in the
+    values' dtype: exactly ``compression.decode_sparse`` of each payload,
+    which adds every slot into a zeroed tensor.
+
+    Each leaf's output is cut into COMPACT_GROUP-element tiles.  The live
+    slots of a tile form one run (ascending indices), found by a binary
+    search of the tile's first index over the slots, outside the kernel.
+    The kernel brings each run in with a DMA of its slot rows, rotates it
+    to rank 0 and spreads it to its places with a shift network
+    (:func:`_expand_tile`), writing every element of the tile.  Values
+    cross as f32.  Under ``jax.vmap`` the mapped axis becomes the
+    kernel's client grid axis: a cohort is one launch.
+    """
+    groups = [-(-ls.size // COMPACT_GROUP) for ls in spec.leaves]
+    slot_base = np.cumsum([0] + [idx.shape[0] for idx, _ in coo])
+    slab = _slab(sum(groups) * 8, slab_rows, interpret) // 8
+    total = -(-sum(groups) // slab) * slab
+
+    starts, counts = [], []
+    for (idx, _), g, base in zip(coo, groups, slot_base[:-1].tolist()):
+        live = (idx > 0) | (jnp.arange(idx.shape[0]) == 0)
+        key = jnp.where(live, idx, jnp.iinfo(jnp.int32).max)
+        before = jnp.searchsorted(
+            key, jnp.arange(g + 1, dtype=jnp.int32) * COMPACT_GROUP,
+            method="scan").astype(jnp.int32)
+        starts.append(base + before[:-1])
+        counts.append(before[1:] - before[:-1])
+    pad = jnp.zeros((total - sum(groups),), jnp.int32)
+    start = jnp.concatenate(starts + [pad])
+    r0 = (start >> (_LOG_EXPAND_LANE + 3)) << 3
+    first = np.zeros((total,), np.int32)
+    first[:sum(groups)] = np.concatenate(
+        [np.arange(g, dtype=np.int32) * COMPACT_GROUP for g in groups])
+    meta = jnp.stack([r0, start - r0 * EXPAND_LANE,
+                      jnp.concatenate(counts + [pad]), jnp.asarray(first)],
+                     axis=1).reshape(-1)
+
+    # r0 + _EXPAND_WIN slot rows must exist for every group's window.
+    slot_rows = (8 * (int(slot_base[-1]) >> (_LOG_EXPAND_LANE + 3))
+                 + _EXPAND_WIN)
+    tail = slot_rows * EXPAND_LANE - int(slot_base[-1])
+
+    def slots(parts, dtype):
+        flat = jnp.concatenate([p.astype(dtype) for p in parts]
+                               + [jnp.zeros((tail,), dtype)])
+        return flat.reshape(slot_rows, EXPAND_LANE)
+
+    call = functools.partial(_expand_call, rows=total * _EXPAND_ROWS,
+                             slab=slab * _EXPAND_ROWS, interpret=interpret)
+    (out,) = _fold_vmap(call)(
+        meta[None], slots([i for i, _ in coo], jnp.int32)[None],
+        slots([v for _, v in coo], jnp.float32)[None])
+
+    def leaf(b, ls):
+        # Whole rows slice out as they lie; a flat slice re-tiles.
+        if ls.size % EXPAND_LANE == 0:
+            return jax.lax.slice_in_dim(out[0], b // EXPAND_LANE,
+                                        (b + ls.size) // EXPAND_LANE)
+        return jax.lax.slice_in_dim(out[0].reshape(-1), b, b + ls.size)
+
+    bases = (np.cumsum([0] + groups) * COMPACT_GROUP).tolist()
+    return [leaf(b, ls).reshape(ls.shape).astype(v.dtype)
+            for b, ls, (_, v) in zip(bases, spec.leaves, coo)]
 
 
 # --------------------------------------------------------------------------

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.codecs import FusedSparseCodec
 from repro.kernels import ops
 from repro.models.paper_models import init_gru_lm, init_vgg
 
@@ -126,6 +127,20 @@ def test_coo_compaction_compiles_for_v5e_under_cohort_vmap(one_chip,
         fused=True, assume_masked=True, quantize=quantize) + COMPACT_STAGE
     assert "fl_seg_compact" in text
     assert " sort(" not in text
+
+
+def test_coo_roundtrip_compiles_for_v5e_under_cohort_vmap(one_chip):
+    """Both halves of the fused COO wire in the round: the GRU embedding
+    at gamma 0.5 for a 32-client cohort, compacted and expanded by
+    kernels, with no sort and no scatter left in the program."""
+    emb = jax.ShapeDtypeStruct((32, WIKITEXT2_VOCAB, 128), jnp.float32,
+                               sharding=one_chip)
+    codec = FusedSparseCodec(gamma=0.5, interpret=False)
+    text = jax.jit(jax.vmap(codec.roundtrip)).lower(emb).compile().as_text()
+    assert "fl_seg_compact" in text
+    assert "fl_seg_expand" in text
+    assert " sort(" not in text
+    assert " scatter(" not in text
 
 
 @pytest.mark.parametrize("leaf", [("gru", "embed"), ("vgg", "conv3b", "w")],
