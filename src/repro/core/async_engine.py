@@ -61,18 +61,17 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
-import time
-from typing import Any, Dict, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.codecs import IdentityCodec
-from repro.core.federated import (_active_attack, _resolve_policies,
+from repro.core.federated import (_active_attack, _resolve_aggregator,
                                   _row_l2, _split_round_key, _wire_feedback,
-                                  make_cohort_compute, make_store_compute,
-                                  make_store_selection)
+                                  aot_compile, make_cohort_compute,
+                                  make_store_compute, make_store_selection)
 from repro.core.hetero import HeteroModel, arrival_stream
 
 PyTree = Any
@@ -224,8 +223,8 @@ class AsyncRoundRunner:
             else HeteroModel(profile="ideal")
         self.traits = hetero.client_traits(num_clients)
         self._with_drop = strategy.hetero is not None
-        _, self._agg_fn = _resolve_policies(
-            strategy.codec, strategy.aggregator, self.smp.normalize)
+        self._agg_fn = _resolve_aggregator(strategy.aggregator,
+                                           self.smp.normalize)
         # Mirrors roundtrip_stacked's pass-through condition — the static
         # analogue of the sync engines' `wired is not uploads` check.
         self._wire_feedback = not (strategy.codec is None
@@ -242,57 +241,9 @@ class AsyncRoundRunner:
         q = np.asarray(self.traits.drop_rate, np.float64)
         self._survival = (1.0 - q ** (self.acfg.max_retries + 1)).astype(
             np.float32)
-        self._compute_fns: Dict[int, Any] = {}
-        self._select_fns: Dict[int, Any] = {}
-        self._store_compute = None
+        # AOT executables, keyed by (program, bucket, input avals); see
+        # federated.aot_compile.
         self._aot_cache: Dict[Any, Any] = {}
-
-    # ---- compiled-program plumbing ----------------------------------------
-    def _aot(self, name: str, fn, args) -> Tuple[Any, float]:
-        """AOT-compile ``fn`` for ``args``' avals (cached); returns
-        ``(compiled, compile_seconds)`` with 0.0 on cache hits."""
-        avals = tuple(
-            (tuple(leaf.shape), str(leaf.dtype))
-            for leaf in jax.tree_util.tree_leaves(args))
-        cache_key = (name, avals)
-        hit = self._aot_cache.get(cache_key)
-        if hit is not None:
-            return hit, 0.0
-        t0 = time.perf_counter()
-        compiled = jax.jit(fn).lower(*args).compile()
-        dt = time.perf_counter() - t0
-        self._aot_cache[cache_key] = compiled
-        return compiled, dt
-
-    def _compute_fn(self, bucket: int):
-        """The engine-shared dispatch sweep for one cohort bucket."""
-        fn = self._compute_fns.get(bucket)
-        if fn is None:
-            fn = make_cohort_compute(
-                self.loss_fn, self.schedule, self.cfg, bucket,
-                codec=self.strategy.codec, sampler=self.smp,
-                attack=self.attack)
-            self._compute_fns[bucket] = fn
-        return fn
-
-    def _select_fn(self, bucket: int):
-        """Store-form selection head for one cohort bucket (sharded
-        dispatch only — the residual gather happens OUTSIDE the program,
-        through ``self.store``)."""
-        fn = self._select_fns.get(bucket)
-        if fn is None:
-            fn = make_store_selection(self.schedule, self.cfg, bucket,
-                                      sampler=self.smp)
-            self._select_fns[bucket] = fn
-        return fn
-
-    def _store_compute_fn(self):
-        """Store-form sweep on pre-gathered cohort residual rows."""
-        if self._store_compute is None:
-            self._store_compute = make_store_compute(
-                self.loss_fn, self.cfg, codec=self.strategy.codec,
-                attack=self.attack)
-        return self._store_compute
 
     # ---- jitted round pieces ----------------------------------------------
     def _gate_impl(self, wired, corrupt_c):
@@ -407,8 +358,11 @@ class AsyncRoundRunner:
         t_arr = jnp.asarray(t, jnp.float32)
         if sharded:
             sel_args = (norms, n_samples, t_arr, sample_key)
-            sel, dt = self._aot(("select", cohort_size),
-                                self._select_fn(cohort_size), sel_args)
+            sel, dt = aot_compile(
+                self._aot_cache, "select", cohort_size,
+                lambda: make_store_selection(self.schedule, self.cfg,
+                                             cohort_size, sampler=self.smp),
+                sel_args)
             compile_s += dt
             part_dev, weights_dev, ids_dev = sel(*sel_args)
             ids_np = np.asarray(ids_dev)
@@ -420,10 +374,14 @@ class AsyncRoundRunner:
             else:
                 cohort_batches = jax.tree.map(
                     lambda x: jnp.take(x, ids_dev, axis=0), client_batches)
-            cargs = (params, cohort_res, cohort_batches, ids_dev, mask_key,
-                     cohort_drift)
-            comp, dt = self._aot("store-compute", self._store_compute_fn(),
-                                 cargs)
+            cargs = (params, cohort_res, cohort_drift, cohort_batches,
+                     ids_dev, mask_key)
+            comp, dt = aot_compile(
+                self._aot_cache, "sweep", cohort_size,
+                lambda: make_store_compute(self.loss_fn, self.cfg,
+                                           codec=self.strategy.codec,
+                                           attack=self.attack),
+                cargs)
             compile_s += dt
             out = dict(comp(*cargs))
             out.update(part=part_dev, weights=weights_dev,
@@ -434,9 +392,13 @@ class AsyncRoundRunner:
             compute_args = (params, residuals, drift_dense, norms,
                             client_batches, n_samples, t_arr, sample_key,
                             mask_key)
-            compute, dt = self._aot(("compute", cohort_size),
-                                    self._compute_fn(cohort_size),
-                                    compute_args)
+            compute, dt = aot_compile(
+                self._aot_cache, "compute", cohort_size,
+                lambda: make_cohort_compute(
+                    self.loss_fn, self.schedule, self.cfg, cohort_size,
+                    codec=self.strategy.codec, sampler=self.smp,
+                    attack=self.attack),
+                compute_args)
             compile_s += dt
             out = compute(*compute_args)
 
@@ -468,7 +430,8 @@ class AsyncRoundRunner:
             corrupt = (rng.random(M) < acfg.corrupt_rate).astype(np.float32)
         if self._inject or acfg.quarantine:
             gate_args = (payload, jnp.asarray(corrupt[cohort_ids]))
-            gate, dt = self._aot("gate", self._gate_impl, gate_args)
+            gate, dt = aot_compile(self._aot_cache, "gate", cohort_size,
+                                   lambda: self._gate_impl, gate_args)
             compile_s += dt
             payload, finite_dev = gate(*gate_args)
             finite_c = np.asarray(finite_dev)
@@ -599,7 +562,8 @@ class AsyncRoundRunner:
                 flush_payload, keep = payload, keep_dev
                 staleness_sum += float(s) * len(buffer_rows)
             flush_args = (params, flush_payload, w_flush, keep)
-            flush, dt = self._aot("flush", self._flush_impl, flush_args)
+            flush, dt = aot_compile(self._aot_cache, "flush", cohort_size,
+                                    lambda: self._flush_impl, flush_args)
             compile_s += dt
             params = flush(*flush_args)
             applied_rows[cur] = 1.0
@@ -678,8 +642,10 @@ class AsyncRoundRunner:
         if sharded:
             close_args = (norms, out["cohort_ids"], out["new_res"],
                           out["uploads"], wired, payload, applied_dev)
-            close, dt = self._aot("close-rows", self._close_rows_impl,
-                                  close_args)
+            close, dt = aot_compile(self._aot_cache, "close-rows",
+                                    cohort_size,
+                                    lambda: self._close_rows_impl,
+                                    close_args)
             compile_s += dt
             rows, norm_upd = close(*close_args)
             if self.cfg.error_feedback:
@@ -692,7 +658,8 @@ class AsyncRoundRunner:
             close_args = (residuals, norms, out["cohort_ids"],
                           out["cohort_res"], out["new_res"], out["uploads"],
                           wired, payload, applied_dev)
-            close, dt = self._aot("close", self._close_impl, close_args)
+            close, dt = aot_compile(self._aot_cache, "close", cohort_size,
+                                    lambda: self._close_impl, close_args)
             compile_s += dt
             residuals, norms = close(*close_args)
 

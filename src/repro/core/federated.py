@@ -1,27 +1,44 @@
-"""The federated round — simulation (vmap over clients) form.
+"""The federated round: one body in three pieces, composed into every form.
 
-``make_federated_round`` builds one jit-able function implementing paper
-Alg. 1/3 server loop body + Alg. 2/4 client bodies:
+Paper Alg. 1/3 server loop body + Alg. 2/4 client bodies, written once:
 
-  1. draw the participation mask from the sampling schedule (static/dynamic),
-  2. every registered client runs its local update (vmap) — non-participants
-     are masked out of the aggregation, which keeps shapes static,
-  3. weighted FedAvg (Eq. 2): Θ_{t+1} = Θ_t + Σ_i w_i · upload_i with
-     w_i = mask_i·n_i / Σ mask_j·n_j.
+* **select** (:func:`make_store_selection`) — the sampler's participation
+  mask and aggregation weights on the full ``(M,)`` arrays, plus the
+  cohort-id buffer: the ``part > 0`` ids sorted ascending, padded with the
+  lowest-id non-participants;
+* **sweep** (:func:`make_store_compute`) — every cohort row's local update,
+  its wire round-trip and the adversary payload;
+* **barrier** (:func:`_make_barrier`) — quarantine gate, upload dropout,
+  weighted FedAvg (Eq. 2): Θ_{t+1} = Θ_t + Σ_i w_i · upload_i with
+  w_i = mask_i·n_i / Σ mask_j·n_j, error-feedback wire feedback, the
+  per-row commit mask ``arrived × finite``, norm-EMA rows and the metrics.
 
 Note on Eq. 1/2: the paper writes an extra 1/m in front of Σ (n_i/n)Θ^i; since
 the n_i/n weights already sum to 1 over the selected set, the extra 1/m would
 shrink the model m-fold.  We take Σ (n_i/n)Θ^i, which matches FedAvg
 (McMahan et al.) and the paper's cited behaviour.
 
-Two execution forms of the same round:
+The forms are compositions of the pieces:
 
-* **oracle** (``make_federated_round``): vmap over ALL registered clients,
-  non-participants zero-weighted — simple, flat in c(t);
-* **cohort engine** (``make_cohort_round`` / ``make_cohort_scan``): gather
-  only the sampled m_t clients into a bucketed padded cohort buffer, run
-  client_update over the cohort axis, scatter residuals back (DESIGN.md
-  §3.5) — per-round work decays with c(t).
+* **cohort engine** (``make_cohort_round`` / ``make_cohort_scan``): select,
+  gather the cohort's rows into a bucketed padded buffer, sweep, barrier,
+  scatter the committed rows back (DESIGN.md §3.5) — per-round work decays
+  with c(t);
+* **oracle** (``make_federated_round``): the same composition with the
+  cohort = all M clients, so the gathers and scatters drop out statically
+  and non-participants are zero-weighted;
+* **store form** (``make_store_round``): select is one program, sweep +
+  barrier the other; state rows move between them through a
+  ``ClientStateStore`` (DESIGN.md §11);
+* **async dispatch** (``make_cohort_compute``): select, gather, sweep; the
+  async engine applies the uploads as they arrive (DESIGN.md §8).
+
+Equivalence across forms is by construction: the participant set and the
+weights come from one select; per-client mask keys are row i of
+``split(mask_key, M)`` whichever rows run; cohort ids are sorted ascending,
+so weighted reductions visit participants in client-id order (padding rows
+and the oracle's non-participants add exact zeros); and every state commit
+is the same ``where(commit, new, old)``.
 
 Every builder takes two further scenario axes (DESIGN.md §5):
 
@@ -33,23 +50,24 @@ Every builder takes two further scenario axes (DESIGN.md §5):
 * ``hetero`` — a :class:`repro.core.hetero.HeteroModel`; its per-client
   drop rates are drawn INSIDE the round (a dropped upload is zero-weighted
   and, under error feedback, leaves that client's residual untouched), so
-  both engines agree bit-exactly on which uploads count.
+  every form agrees bit-exactly on which uploads count.
 
 The pod (shard_map) form of the same round lives in
 ``repro.launch.fedtrain`` — identical math, collectives instead of vmap.
 
-Every round body names its layers with ``jax.named_scope``, so each op's
+Every piece names its layers with ``jax.named_scope``, so each op's
 ``op_name`` (and a device trace) says which layer it belongs to:
-``fl.local_update`` (selection, batch gathers, the client updates),
+``fl.local_update`` (selection, row gathers, the client updates),
 ``fl.mask`` (inside it, ``client.client_update``), ``fl.encode`` /
 ``fl.decode`` (``UploadCodec.roundtrip``) and ``fl.aggregate`` (the
-quarantine gate through the metrics).  Scopes change metadata only.
+barrier and the state scatter).  Scopes change metadata only.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import inspect
+import time
 from typing import Any, Callable
 
 import jax
@@ -58,20 +76,19 @@ import jax.numpy as jnp
 from repro.core.attacks import attack_keys
 from repro.core.client import ClientConfig, stacked_client_update
 from repro.core.codecs import roundtrip_stacked
-from repro.core.sampling import (SamplingSchedule, UniformSampler,
-                                 participation_mask)
+from repro.core.sampling import SamplingSchedule, UniformSampler
 
 PyTree = Any
 
 __all__ = ["FederatedConfig", "make_federated_round", "make_cohort_round",
            "make_cohort_scan", "make_cohort_compute", "cohort_select",
            "fedavg_aggregate", "make_store_selection", "make_store_compute",
-           "make_store_round", "StoreRound"]
+           "make_store_round", "StoreRound", "aot_compile"]
 
 
-def _resolve_policies(codec, aggregator, normalize: bool = True):
-    """Normalize the optional (codec, aggregator) pair every round builder
-    takes: identity wire + plain fedavg when unset.
+def _resolve_aggregator(aggregator, normalize: bool = True):
+    """The aggregation call ``agg_fn(params, uploads, weights, semantics)``
+    of the optional ``aggregator`` (plain fedavg when unset).
 
     ``normalize`` binds the sampler's weight semantics into the returned
     aggregation call.  Legacy aggregators registered against the PR-4
@@ -104,25 +121,25 @@ def _resolve_policies(codec, aggregator, normalize: bool = True):
             f"aggregator {getattr(aggregator, 'name', fn)!r} does not accept "
             "normalize= but the sampler emits Horvitz-Thompson weights "
             "(normalize=False); extend its fn signature")
-
-    def apply_wire(stacked):
-        return roundtrip_stacked(codec, stacked)
-
-    return apply_wire, agg_fn
-
-
-def _is_plain(sampler, hetero, attack=None) -> bool:
-    """True when the round reduces to the original schedule-only body —
-    the path kept verbatim so default rounds stay bit-identical.  An
-    active attack routes to the generalized body (adversary injection
-    needs the full metering path)."""
-    return (hetero is None and attack is None
-            and (sampler is None or isinstance(sampler, UniformSampler)))
+    return agg_fn
 
 
 def _active_attack(attack):
     """Normalize the optional attack: a zero-fraction model is no attack."""
     return attack if attack is not None and attack.active else None
+
+
+def _adversaries(attack, num_clients: int) -> jnp.ndarray:
+    """The attack's static ``(M,)`` 0/1 adversary assignment."""
+    return jnp.asarray(attack.adversary_mask(num_clients), jnp.float32)
+
+
+def _rows(tree: PyTree, ids) -> PyTree:
+    """Client rows ``ids`` of a client-stacked pytree (``None`` leaves
+    stay ``None``).  ``ids=None`` is the whole population: no gather."""
+    if ids is None:
+        return tree
+    return jax.tree.map(lambda x: jnp.take(x, ids, axis=0), tree)
 
 
 def _row_l2(stacked: PyTree) -> jnp.ndarray:
@@ -135,8 +152,8 @@ def _row_l2(stacked: PyTree) -> jnp.ndarray:
 
 def _finite_rows(stacked: PyTree) -> jnp.ndarray:
     """1.0 for client rows whose every leaf entry is finite, else 0.0 —
-    the decode-boundary quarantine gate, shared by both sync engines (the
-    async engine applies the same check event-by-event, DESIGN.md §8)."""
+    the decode-boundary quarantine gate of the barrier (the async engine
+    applies the same check event-by-event, DESIGN.md §8)."""
     ok = None
     for leaf in jax.tree_util.tree_leaves(stacked):
         leaf_ok = jnp.all(jnp.isfinite(leaf.astype(jnp.float32)),
@@ -157,22 +174,16 @@ def _zero_rows(stacked: PyTree, keep: jnp.ndarray) -> PyTree:
         stacked)
 
 
-def _attack_payload(attack, wired, adv, mask_key, num_clients,
-                    cohort_ids=None):
+def _attack_payload(attack, wired, mask_key, num_clients, cohort_ids):
     """What the server actually decodes: ``wired`` with adversary rows
-    transformed.  ``adv`` is the full ``(M,)`` assignment; ``cohort_ids``
-    gathers it (and the per-client attack keys) onto cohort rows so both
-    engines perturb client i identically.  Returns ``wired`` itself when
-    no attack is active — downstream ``is not`` checks stay exact."""
+    transformed.  The ``(M,)`` assignment and per-client attack keys are
+    gathered onto the cohort rows (``cohort_ids=None``: all M), so every
+    form perturbs client i identically.  Returns ``wired`` itself when no
+    attack is active — downstream ``is not`` checks stay exact."""
     if attack is None:
         return wired
-    keys = None
-    if attack.needs_keys:
-        keys = attack_keys(mask_key, num_clients)
-    if cohort_ids is not None:
-        adv = jnp.take(adv, cohort_ids)
-        if keys is not None:
-            keys = jnp.take(keys, cohort_ids, axis=0)
+    keys = attack_keys(mask_key, num_clients) if attack.needs_keys else None
+    adv, keys = _rows((_adversaries(attack, num_clients), keys), cohort_ids)
     return attack.apply_stacked(wired, adv, keys)
 
 
@@ -214,16 +225,6 @@ def fedavg_aggregate(global_params: PyTree, uploads: PyTree,
     return jax.tree.map(combine, global_params, uploads)
 
 
-def _round_extras(sampler, hetero, cfg):
-    """Shared setup for the generalized (non-plain) round bodies: the
-    resolved sampler and the static per-client drop-rate vector (or None)."""
-    smp = sampler if sampler is not None else UniformSampler()
-    drop = None
-    if hetero is not None:
-        drop = jnp.asarray(hetero.drop_rates(cfg.num_clients), jnp.float32)
-    return smp, drop
-
-
 def _split_round_key(key, with_drop: bool):
     """(sample, mask[, drop]) subkeys; the 2-way split is kept verbatim for
     hetero-free rounds so default rounds stay bit-identical."""
@@ -252,12 +253,23 @@ def _apply_dropout(part, weights, drop, drop_key, normalize):
 
 def _commit_rows(old: PyTree, new: PyTree, commit: jnp.ndarray) -> PyTree:
     """Per-row state commit: keep ``new[i]`` where ``commit[i] > 0``, else
-    the round-entry ``old[i]`` — the same ``where`` every engine's EF
-    scatter runs, shared so the drift tree commits identically."""
+    the round-entry ``old[i]`` — the same ``where`` every engine's state
+    commit runs, shared so every state tree commits identically."""
     return jax.tree.map(
         lambda o, n: jnp.where(
             commit.reshape((-1,) + (1,) * (n.ndim - 1)) > 0, n, o),
         old, new)
+
+
+def _scatter_rows(full: PyTree, cohort_old: PyTree, rows: PyTree,
+                  commit: jnp.ndarray, ids) -> PyTree:
+    """Commit cohort ``rows`` into the full ``(M, …)`` state: the
+    commit-masked ``where`` on the cohort, then ``at[ids].set`` (no
+    scatter when ``ids=None``, the whole population)."""
+    kept = _commit_rows(cohort_old, rows, commit)
+    if ids is None:
+        return kept
+    return jax.tree.map(lambda o, k: o.at[ids].set(k), full, kept)
 
 
 def _wire_feedback(new_res: PyTree, uploads: PyTree, wired: PyTree) -> PyTree:
@@ -285,20 +297,256 @@ def _wire_feedback(new_res: PyTree, uploads: PyTree, wired: PyTree) -> PyTree:
     return jax.tree.map(lambda r, u, w: r + (u - w), new_res, uploads, wired)
 
 
-def _wrap_plain(round_impl, uses_drift: bool):
-    """Adapt the plain round body ``(params, residuals, drift, batches,
-    n_samples, t, key) -> (p, r, d, metrics)`` to its public signature:
-    the drift slot appears (after ``residuals``) only when the objective
-    carries drift state."""
-    if uses_drift:
-        return round_impl
+def aot_compile(cache: dict, tag: str, bucket: int, build: Callable, args,
+                seg_len: int = 1):
+    """AOT-compile ``build()`` for ``args`` once, cached in ``cache``.
 
-    def round_fn(params, residuals, client_batches, n_samples, t, key):
-        p, r, _, m = round_impl(params, residuals, None, client_batches,
-                                n_samples, t, key)
-        return p, r, m
+    The key is ``(tag, bucket, seg_len, input avals)``, so a later call
+    with differently-shaped data recompiles instead of hitting a stale
+    executable (AOT calls don't retrace the way plain jit does).  Returns
+    ``(executable, compile_s)`` with ``compile_s = 0.0`` on a hit.  A miss
+    builds the program and compiles it inside an ``fl.compile`` host span.
+    """
+    avals = tuple((tuple(leaf.shape), str(leaf.dtype))
+                  for leaf in jax.tree_util.tree_leaves(args))
+    cache_key = (tag, bucket, seg_len, avals)
+    hit = cache.get(cache_key)
+    if hit is not None:
+        return hit, 0.0
+    with jax.profiler.TraceAnnotation("fl.compile", bucket=bucket):
+        fn = build()
+        t0 = time.perf_counter()
+        compiled = jax.jit(fn).lower(*args).compile()
+        compile_s = time.perf_counter() - t0
+    cache[cache_key] = compiled
+    return compiled, compile_s
 
-    return round_fn
+
+# ---------------------------------------------------------------------------
+# The three pieces
+# ---------------------------------------------------------------------------
+def make_store_selection(schedule: SamplingSchedule, cfg: FederatedConfig,
+                         cohort_size: int, *, sampler=None):
+    """The round's **select** piece, jittable on its own.
+
+    Returns ``select(norms, n_samples, t, sample_key) -> (part, weights,
+    cohort_ids)``: the sampler's participation mask and pre-dropout
+    aggregation weights on the full ``(M,)`` arrays, plus the
+    ``cohort_size`` cohort ids — the ``part > 0`` ids sorted ascending,
+    padded with the lowest-id non-participants.  ``cohort_size`` must
+    upper-bound the participant count (``ClientSampler.cohort_bucket``).
+    Pass ``norms=None`` for non-adaptive samplers.
+    """
+    if not (0 < cohort_size <= cfg.num_clients):
+        raise ValueError(
+            f"cohort_size {cohort_size} not in (0, {cfg.num_clients}]")
+    smp = sampler if sampler is not None else UniformSampler()
+    M = cfg.num_clients
+
+    def select(norms, n_samples, t, sample_key):
+        with jax.named_scope("fl.local_update"):
+            part, weights = smp.select(sample_key, schedule, t, M, n_samples,
+                                       norms)
+            ids = jnp.arange(M, dtype=jnp.int32)
+            order = jnp.argsort(jnp.where(part > 0, ids, ids + M))
+            cohort_ids = jnp.sort(order[:cohort_size])
+        return part, weights, cohort_ids
+
+    return select
+
+
+def cohort_select(sample_key: jax.Array, schedule: SamplingSchedule, t,
+                  num_clients: int, cohort_size: int):
+    """The uniform sampler's cohort, as the server runs it: ``(cohort_ids,
+    valid)`` with ``valid[i] = 1`` iff cohort member i is a true
+    participant.  Identical participant set to
+    :func:`repro.core.sampling.participation_mask` under the same key."""
+    cfg = FederatedConfig(num_clients=num_clients, client=ClientConfig())
+    select = make_store_selection(schedule, cfg, cohort_size)
+    part, _, cohort_ids = select(None, jnp.ones((num_clients,), jnp.float32),
+                                 t, sample_key)
+    return cohort_ids, jnp.take(part, cohort_ids)
+
+
+def make_store_compute(loss_fn: Callable, cfg: FederatedConfig, *,
+                       codec=None, attack=None):
+    """The round's **sweep** piece: the client side over cohort rows.
+
+    Returns ``sweep(params, cohort_res, cohort_drift, cohort_batches,
+    cohort_ids, mask_key) -> dict`` with keys ``uploads`` / ``wired``
+    (pre-/post-wire stacked uploads), ``attacked`` (the payload the server
+    decodes: ``wired`` with adversary rows perturbed — the same object when
+    no attack is active), ``new_res`` / ``new_drift`` (post-round state
+    candidates) and ``losses``.  Per-client mask keys are row i of
+    ``split(mask_key, M)``, so client i's masking draw does not depend on
+    which form ran it; ``cohort_ids=None`` sweeps all M clients.
+    ``cohort_drift`` is None unless ``cfg.client.objective.uses_drift``.
+    """
+    attack = _active_attack(attack)
+    M = cfg.num_clients
+
+    def sweep(params, cohort_res, cohort_drift, cohort_batches, cohort_ids,
+              mask_key):
+        with jax.named_scope("fl.local_update"):
+            mask_keys = _rows(jax.random.split(mask_key, M), cohort_ids)
+            # A module global looked up at trace time, so a patched
+            # ``federated.stacked_client_update`` takes effect.
+            uploads, new_res, new_drift, losses = stacked_client_update(
+                loss_fn, params, cohort_batches, mask_keys, cfg.client,
+                cohort_res, cfg.error_feedback, cohort_drift)
+        wired = roundtrip_stacked(codec, uploads)
+        return {
+            "uploads": uploads,
+            "new_res": new_res,
+            "new_drift": new_drift,
+            "losses": losses,
+            "wired": wired,
+            "attacked": _attack_payload(attack, wired, mask_key, M,
+                                        cohort_ids),
+        }
+
+    return sweep
+
+
+def _make_barrier(cfg: FederatedConfig, *, aggregator=None, sampler=None,
+                  hetero=None, attack=None):
+    """The round's **barrier** piece.
+
+    Returns ``(barrier, sampler, with_drop)``.  ``barrier(params, c, norms,
+    drop_key) -> (new_params, res_rows, drift_rows, commit, norm_upd,
+    metrics)`` consumes the sweep's dict ``c`` extended with the select's
+    ``part`` / ``weights`` and ``cohort_ids`` (``None``: all M rows):
+
+    * ``res_rows`` — the post-round residual candidates, EF wire-loss
+      feedback folded in on the HONEST (uploads, wired) pair: a client's
+      residual reflects what IT failed to ship, not what an attacker
+      forged in its name;
+    * ``drift_rows`` — the FedDyn drift candidates (None without drift);
+    * ``commit`` — per cohort row, "this upload applied": it arrived and
+      survived the quarantine gate.  A dropped or quarantined upload
+      discards the whole local update, so its state rows must stay at the
+      round entry; non-participants and padding rows never commit;
+    * ``norm_upd`` — the adaptive sampler's norm-EMA rows (None otherwise;
+      uncommitted rows carry the old value), fed by what the server saw:
+      attacked rows feed their forged norms in.
+
+    The in-program forms pass ``state=(residuals, drift)``, the full
+    ``(M, …)`` trees, and ``c`` carrying the gathered round-entry rows
+    ``cohort_res`` / ``cohort_drift``: the barrier then scatters the
+    committed rows back (residuals only under error feedback; drift
+    independent of it, since h_k tracks the honest local trajectory, not
+    the wire) and returns ``(new_params, residuals, drift, norms,
+    metrics)``.
+    """
+    smp = sampler if sampler is not None else UniformSampler()
+    drop = None
+    if hetero is not None:
+        drop = jnp.asarray(hetero.drop_rates(cfg.num_clients), jnp.float32)
+    agg_fn = _resolve_aggregator(aggregator, smp.normalize)
+    attack = _active_attack(attack)
+
+    def barrier(params, c, norms, drop_key, state=None):
+        ids, part, payload = c["cohort_ids"], c["part"], c["attacked"]
+        with jax.named_scope("fl.aggregate"):
+            finite = _finite_rows(payload)
+            arrived, weights = _apply_dropout(part, c["weights"], drop,
+                                              drop_key, smp.normalize)
+            valid, arr_c, w_c = _rows((part, arrived, weights), ids)
+            new_params = agg_fn(params, _zero_rows(payload, finite),
+                                w_c * finite, cfg.client.upload)
+            commit = arr_c * finite
+            res_rows = c["new_res"]
+            if cfg.error_feedback and c["wired"] is not c["uploads"]:
+                # Wire loss (int8 quantisation, slot truncation) is real
+                # masked-out mass: feed it back like any other residual.
+                res_rows = _wire_feedback(res_rows, c["uploads"], c["wired"])
+            norm_upd = None
+            if smp.adaptive:
+                old = _rows(norms, ids)
+                norm_upd = jnp.where(
+                    commit > 0,
+                    (1.0 - smp.ema) * old + smp.ema * _row_l2(payload), old)
+            # The loss sum runs over the (M,) population in every form, so
+            # its order (and bits) do not depend on the bucket.  An empty
+            # round (the threshold sampler's random count can be 0) is a
+            # no-op for the params; report NaN, not a fabricated 0.
+            rows = jnp.arange(part.shape[0]) if ids is None else ids
+            losses = jnp.zeros_like(part).at[rows].set(c["losses"] * valid)
+            n_part = jnp.sum(part)
+            metrics = {
+                "mean_loss": jnp.where(
+                    n_part > 0,
+                    jnp.sum(losses) / jnp.maximum(n_part, 1.0), jnp.nan),
+                "num_sampled": n_part,
+                "quarantined": jnp.sum(arr_c * (1.0 - finite)),
+            }
+            if attack is not None:
+                metrics["num_adversarial"] = jnp.sum(
+                    part * _adversaries(attack, cfg.num_clients))
+            if drop is not None:
+                metrics["part_mask"] = part
+                metrics["arrived_mask"] = arrived
+                metrics["num_arrived"] = jnp.sum(arrived)
+            if state is None:
+                return (new_params, res_rows, c["new_drift"], commit,
+                        norm_upd, metrics)
+            residuals, drift = state
+            if cfg.error_feedback:
+                residuals = _scatter_rows(residuals, c["cohort_res"],
+                                          res_rows, commit, ids)
+            if drift is not None:
+                drift = _scatter_rows(drift, c["cohort_drift"],
+                                      c["new_drift"], commit, ids)
+            if norm_upd is not None:
+                norms = norm_upd if ids is None else \
+                    norms.at[ids].set(norm_upd)
+        return new_params, residuals, drift, norms, metrics
+
+    return barrier, smp, drop is not None
+
+
+# ---------------------------------------------------------------------------
+# The forms
+# ---------------------------------------------------------------------------
+def _dispatch(select, sweep, gather: bool):
+    """select → row gather → sweep, one traceable function with the
+    signature of :func:`make_cohort_compute`'s ``compute``.  ``gather=False``
+    (a cohort of all M clients) drops the gathers statically and reports
+    ``cohort_ids=None``."""
+    def compute(params, residuals, drift, norms, client_batches, n_samples,
+                t, sample_key, mask_key):
+        part, weights, ids = select(norms, n_samples, t, sample_key)
+        ids = ids if gather else None
+        with jax.named_scope("fl.local_update"):
+            cohort_res, cohort_drift, cohort_batches = _rows(
+                (residuals, drift, client_batches), ids)
+        c = sweep(params, cohort_res, cohort_drift, cohort_batches, ids,
+                  mask_key)
+        return dict(c, part=part, weights=weights, cohort_ids=ids,
+                    cohort_res=cohort_res, cohort_drift=cohort_drift)
+
+    return compute
+
+
+def make_cohort_compute(loss_fn: Callable, schedule: SamplingSchedule,
+                        cfg: FederatedConfig, cohort_size: int, *,
+                        codec=None, sampler=None, attack=None):
+    """The round's client side — select, gather, sweep — and nothing after
+    it: the async buffered engine (``repro.core.async_engine``) runs it as
+    the round's *dispatch* and applies the uploads event by event.
+
+    Returns ``compute(params, residuals, drift, norms, client_batches,
+    n_samples, t, sample_key, mask_key) -> dict``: the sweep's keys (see
+    :func:`make_store_compute`) plus ``part`` / ``weights`` (full ``(M,)``
+    selection mask and pre-dropout weights), ``cohort_ids`` and
+    ``cohort_res`` / ``cohort_drift`` (round-entry state rows, gathered).
+    Pass ``norms=None`` for non-adaptive samplers and ``drift=None`` unless
+    ``cfg.client.objective.uses_drift``.
+    """
+    select = make_store_selection(schedule, cfg, cohort_size,
+                                  sampler=sampler)
+    sweep = make_store_compute(loss_fn, cfg, codec=codec, attack=attack)
+    return _dispatch(select, sweep, gather=True)
 
 
 def _wrap_round(round_impl, uses_drift: bool, adaptive: bool):
@@ -329,10 +577,48 @@ def _wrap_round(round_impl, uses_drift: bool, adaptive: bool):
     return round_fn
 
 
+def make_cohort_round(loss_fn: Callable, schedule: SamplingSchedule,
+                      cfg: FederatedConfig, cohort_size: int, *,
+                      codec=None, aggregator=None, sampler=None, hetero=None,
+                      attack=None):
+    """Cohort-engine form of ``make_federated_round``: same signature(s) and
+    math, but client_update runs over ``cohort_size`` (static) clients
+    instead of ``cfg.num_clients``.
+
+    One program: select, gather the cohort's batch shards and state rows,
+    sweep, barrier, and scatter the committed rows back.  Requires
+    ``cohort_size`` to upper-bound the sampler's participant count for
+    every round it is dispatched to — the server guarantees this via
+    ``ClientSampler.cohort_bucket`` (``SamplingSchedule.bucket_for`` for
+    the default uniform sampler).  ``cohort_size == cfg.num_clients`` is
+    the oracle: gathers and scatters drop out statically.
+    """
+    select = make_store_selection(schedule, cfg, cohort_size,
+                                  sampler=sampler)
+    sweep = make_store_compute(loss_fn, cfg, codec=codec, attack=attack)
+    dispatch = _dispatch(select, sweep,
+                         gather=cohort_size < cfg.num_clients)
+    barrier, smp, with_drop = _make_barrier(
+        cfg, aggregator=aggregator, sampler=sampler, hetero=hetero,
+        attack=attack)
+
+    def round_impl(params, residuals, drift, norms, client_batches,
+                   n_samples, t, key):
+        with jax.named_scope("fl.local_update"):
+            sample_key, mask_key, drop_key = _split_round_key(key, with_drop)
+        c = dispatch(params, residuals, drift, norms, client_batches,
+                     n_samples, t, sample_key, mask_key)
+        return barrier(params, c, norms, drop_key, state=(residuals, drift))
+
+    return _wrap_round(round_impl, cfg.client.objective.uses_drift,
+                       smp.adaptive)
+
+
 def make_federated_round(loss_fn: Callable, schedule: SamplingSchedule,
                          cfg: FederatedConfig, *, codec=None, aggregator=None,
                          sampler=None, hetero=None, attack=None):
-    """Build the full-population (oracle) round program.
+    """Build the full-population (oracle) round program: the cohort round
+    with a cohort of all ``cfg.num_clients`` clients, gather-free.
 
     Returns ``round_fn(params, residuals, client_batches, n_samples, t, key)
     -> (params, residuals, metrics)`` — or, when ``sampler.adaptive``,
@@ -359,452 +645,14 @@ def make_federated_round(loss_fn: Callable, schedule: SamplingSchedule,
     simulation; ``attack`` (a :class:`repro.core.attacks.AttackModel`)
     perturbs the adversary rows of the decoded payload before aggregation.
 
-    Both bodies gate the decoded payload through the non-finite quarantine
-    (``metrics["quarantined"]``): a NaN/Inf upload is zero-weighted and
-    zeroed out instead of poisoning Θ, matching the async engine's gate.
+    The barrier gates the decoded payload through the non-finite
+    quarantine (``metrics["quarantined"]``): a NaN/Inf upload is
+    zero-weighted and zeroed out instead of poisoning Θ, matching the
+    async engine's gate.
     """
-    attack = _active_attack(attack)
-    uses_drift = cfg.client.objective.uses_drift
-    if _is_plain(sampler, hetero, attack):
-        apply_wire, agg_fn = _resolve_policies(codec, aggregator)
-
-        def plain_impl(params, residuals, drift, client_batches, n_samples,
-                       t, key):
-            with jax.named_scope("fl.local_update"):
-                sample_key, mask_key = jax.random.split(key)
-                part = participation_mask(sample_key, schedule, t,
-                                          cfg.num_clients)
-                mask_keys = jax.random.split(mask_key, cfg.num_clients)
-
-                (uploads, new_residuals, new_drift,
-                 losses) = stacked_client_update(
-                    loss_fn, params, client_batches, mask_keys, cfg.client,
-                    residuals, cfg.error_feedback, drift)
-
-            wired = apply_wire(uploads)
-            with jax.named_scope("fl.aggregate"):
-                finite = _finite_rows(wired)
-                weights = part * n_samples * finite
-                new_params = agg_fn(params, _zero_rows(wired, finite), weights,
-                                    cfg.client.upload)
-                if cfg.error_feedback:
-                    if wired is not uploads:
-                        # Wire loss (int8 quantisation, slot truncation) is
-                        # real masked-out mass: feed it back like any other
-                        # residual so error feedback compensates for the codec
-                        # too.  Exact no-op for bit-exact wires (u - w == 0).
-                        new_residuals = _wire_feedback(new_residuals, uploads,
-                                                       wired)
-                    # Non-participants did not really run this round: keep
-                    # their old residual; participants reset to the post-mask
-                    # remainder.  Quarantined rows count as non-participants
-                    # (their whole update was discarded at the server).
-                    new_residuals = _commit_rows(residuals, new_residuals,
-                                                 part * finite)
-                else:
-                    new_residuals = residuals
-
-                if uses_drift:
-                    # Drift advances under the same gate as the residuals (the
-                    # upload applied), but independent of error_feedback: h_k
-                    # tracks the honest local trajectory, not the wire.
-                    new_drift = _commit_rows(drift, new_drift, part * finite)
-                else:
-                    new_drift = drift
-
-                metrics = {
-                    "mean_loss": jnp.sum(losses * part)
-                    / jnp.maximum(jnp.sum(part), 1.0),
-                    "num_sampled": jnp.sum(part),
-                    "quarantined": jnp.sum(part * (1.0 - finite)),
-                }
-            return new_params, new_residuals, new_drift, metrics
-
-        return _wrap_plain(plain_impl, uses_drift)
-
-    smp, drop = _round_extras(sampler, hetero, cfg)
-    apply_wire, agg_fn = _resolve_policies(codec, aggregator, smp.normalize)
-    adv = None
-    if attack is not None:
-        adv = jnp.asarray(attack.adversary_mask(cfg.num_clients),
-                          jnp.float32)
-
-    def round_impl(params, residuals, drift, norms, client_batches,
-                   n_samples, t, key):
-        M = cfg.num_clients
-        with jax.named_scope("fl.local_update"):
-            sample_key, mask_key, drop_key = _split_round_key(
-                key, drop is not None)
-            part, weights = smp.select(sample_key, schedule, t, M, n_samples,
-                                       norms)
-            mask_keys = jax.random.split(mask_key, M)
-
-            uploads, new_residuals, new_drift, losses = stacked_client_update(
-                loss_fn, params, client_batches, mask_keys, cfg.client,
-                residuals, cfg.error_feedback, drift)
-
-        wired = apply_wire(uploads)
-        # What the server decodes: adversary rows perturbed, then the
-        # non-finite quarantine gate.  EF wire-loss feedback below stays on
-        # the HONEST (uploads, wired) pair — a client's residual reflects
-        # what IT failed to ship, not what an attacker forged in its name.
-        payload = _attack_payload(attack, wired, adv, mask_key, M)
-        with jax.named_scope("fl.aggregate"):
-            finite = _finite_rows(payload)
-            arrived, weights = _apply_dropout(part, weights, drop, drop_key,
-                                              smp.normalize)
-            weights = weights * finite
-            new_params = agg_fn(params, _zero_rows(payload, finite), weights,
-                                cfg.client.upload)
-            if cfg.error_feedback:
-                if wired is not uploads:
-                    new_residuals = _wire_feedback(new_residuals, uploads,
-                                                   wired)
-                # Residuals advance only for clients whose upload ARRIVED (and
-                # survived quarantine): a dropped upload discards the whole
-                # local update, so its residual must stay consistent with the
-                # global model the client re-downloads next round.
-                new_residuals = _commit_rows(residuals, new_residuals,
-                                             arrived * finite)
-            else:
-                new_residuals = residuals
-
-            if uses_drift:
-                # Same arrival gate as the residuals; independent of
-                # error_feedback (drift tracks the honest local trajectory).
-                new_drift = _commit_rows(drift, new_drift, arrived * finite)
-            else:
-                new_drift = drift
-
-            new_norms = norms
-            if smp.adaptive:
-                # The tracker observes what the server saw — attacked rows
-                # feed their forged norms in, exactly the signal a
-                # norm-adaptive sampler would really receive under attack.
-                obs = _row_l2(payload)
-                new_norms = jnp.where(
-                    arrived * finite > 0,
-                    (1.0 - smp.ema) * norms + smp.ema * obs, norms)
-
-            # An empty round (the threshold sampler's random count can be 0) is
-            # a no-op for the params; report NaN, not a fabricated 0.0 loss.
-            n_part = jnp.sum(part)
-            metrics = {
-                "mean_loss": jnp.where(
-                    n_part > 0,
-                    jnp.sum(losses * part) / jnp.maximum(n_part, 1.0),
-                    jnp.nan),
-                "num_sampled": n_part,
-                "quarantined": jnp.sum(arrived * (1.0 - finite)),
-            }
-            if attack is not None:
-                metrics["num_adversarial"] = jnp.sum(part * adv)
-            if drop is not None:
-                metrics["part_mask"] = part
-                metrics["arrived_mask"] = arrived
-                metrics["num_arrived"] = jnp.sum(arrived)
-        return new_params, new_residuals, new_drift, new_norms, metrics
-
-    return _wrap_round(round_impl, uses_drift, smp.adaptive)
-
-
-# ---------------------------------------------------------------------------
-# Cohort execution engine (DESIGN.md §3.5)
-# ---------------------------------------------------------------------------
-# The oracle above runs EVERY registered client and multiplies
-# non-participants by zero — per-round compute/memory is flat in c(t).  The
-# cohort engine materializes only a padded cohort buffer of static size
-# ``cohort_size`` (a SamplingSchedule.bucket_ladder entry >= m_t): gather the
-# m_t participants' batch shards + error-feedback residuals into the buffer,
-# vmap client_update over the cohort axis only, and scatter residuals back
-# under the participation mask.  Padding slots (cohort rank >= m_t) execute
-# but are masked out of the aggregation — exactly the oracle's zero-weight
-# treatment, restricted to at most bucket-m_t clients instead of M-m_t.
-#
-# Equivalence with the oracle is by construction:
-#   * the participant SET is identical — both rank the same uniform draw
-#     from ``sample_key`` and keep ranks < m_t;
-#   * per-client mask keys are row i of split(mask_key, M) in both paths;
-#   * cohort ids are sorted ascending so the weighted reduction visits
-#     participants in the same client-id order as the oracle (its extra
-#     terms are exact zeros).
-
-
-def cohort_select(sample_key: jax.Array, schedule: SamplingSchedule, t,
-                  num_clients: int, cohort_size: int):
-    """Pick the round's cohort: ``(cohort_ids, valid)`` with ids sorted
-    ascending and ``valid[i] = 1`` iff cohort member i is a true participant
-    (its global rank < m_t).  Identical participant set to
-    :func:`repro.core.sampling.participation_mask` under the same key."""
-    m = schedule.num_clients(t, num_clients)
-    scores = jax.random.uniform(sample_key, (num_clients,))
-    order = jnp.argsort(scores)                  # ids by ascending score
-    ranks = jnp.argsort(order)                   # rank of each client id
-    cohort_ids = jnp.sort(order[:cohort_size])   # participant superset
-    valid = (jnp.take(ranks, cohort_ids) < m).astype(jnp.float32)
-    return cohort_ids, valid
-
-
-def make_cohort_compute(loss_fn: Callable, schedule: SamplingSchedule,
-                        cfg: FederatedConfig, cohort_size: int, *,
-                        codec=None, sampler=None, attack=None):
-    """The round's *client-side sweep*, shared between execution engines:
-    selection → cohort gather → local updates → wire round-trip — and
-    nothing after it (no dropout draw, no aggregation, no state commit).
-
-    The sync cohort engine (``make_cohort_round``) runs this then applies
-    its barrier aggregation in the same jitted program; the async buffered
-    engine (``repro.core.async_engine``) runs it as the round's *dispatch*
-    phase and applies the uploads event-by-event as they arrive.  Both see
-    the identical uploads because the whole sweep is a pure function of
-    ``(params, residuals, norms, t, sample_key, mask_key)``.
-
-    Returns ``compute(params, residuals, drift, norms, client_batches,
-    n_samples, t, sample_key, mask_key) -> dict`` with keys ``part`` /
-    ``weights`` (full ``(M,)`` selection mask and pre-dropout aggregation
-    weights), ``cohort_ids`` (sorted ascending, padded with the lowest-id
-    non-participants), ``cohort_res`` / ``cohort_drift`` (round-entry
-    state rows, gathered), ``uploads`` / ``wired`` (pre-/post-wire stacked
-    uploads), ``attacked`` (the payload the server decodes: ``wired`` with
-    adversary rows perturbed — the same object when no attack is active),
-    ``new_res`` / ``new_drift`` (post-round state candidates) and
-    ``losses`` — everything a barrier or a buffer needs to finish the
-    round.  Pass ``norms=None`` for non-adaptive samplers and
-    ``drift=None`` unless ``cfg.client.objective.uses_drift``.
-    """
-    if not (0 < cohort_size <= cfg.num_clients):
-        raise ValueError(
-            f"cohort_size {cohort_size} not in (0, {cfg.num_clients}]")
-    smp = sampler if sampler is not None else UniformSampler()
-    attack = _active_attack(attack)
-    adv = None
-    if attack is not None:
-        adv = jnp.asarray(attack.adversary_mask(cfg.num_clients),
-                          jnp.float32)
-
-    def compute(params, residuals, drift, norms, client_batches, n_samples,
-                t, sample_key, mask_key):
-        with jax.named_scope("fl.local_update"):
-            M = cfg.num_clients
-            # Selection runs on the full (M,) arrays — identical ops to the
-            # oracle — then the cohort buffer gathers the sampler's ids.
-            part, weights = smp.select(sample_key, schedule, t, M, n_samples,
-                                       norms)
-            ids = jnp.arange(M, dtype=jnp.int32)
-            order = jnp.argsort(jnp.where(part > 0, ids, ids + M))
-            cohort_ids = jnp.sort(order[:cohort_size])
-
-            def gather(x):
-                return jnp.take(x, cohort_ids, axis=0)
-
-            cohort_batches = jax.tree.map(gather, client_batches)
-            cohort_res = jax.tree.map(gather, residuals)
-            cohort_drift = jax.tree.map(gather, drift)  # None stays None
-            mask_keys = jnp.take(
-                jax.random.split(mask_key, M), cohort_ids, axis=0)
-
-            uploads, new_res, new_drift, losses = stacked_client_update(
-                loss_fn, params, cohort_batches, mask_keys, cfg.client,
-                cohort_res, cfg.error_feedback, cohort_drift)
-
-        wired = roundtrip_stacked(codec, uploads)
-        attacked = _attack_payload(attack, wired, adv, mask_key, M,
-                                   cohort_ids=cohort_ids)
-        return {
-            "part": part,
-            "weights": weights,
-            "cohort_ids": cohort_ids,
-            "cohort_res": cohort_res,
-            "cohort_drift": cohort_drift,
-            "uploads": uploads,
-            "new_res": new_res,
-            "new_drift": new_drift,
-            "losses": losses,
-            "wired": wired,
-            "attacked": attacked,
-        }
-
-    return compute
-
-
-def make_cohort_round(loss_fn: Callable, schedule: SamplingSchedule,
-                      cfg: FederatedConfig, cohort_size: int, *,
-                      codec=None, aggregator=None, sampler=None, hetero=None,
-                      attack=None):
-    """Cohort-engine form of ``make_federated_round``: same signature(s) and
-    math, but client_update runs over ``cohort_size`` (static) clients
-    instead of ``cfg.num_clients``.
-
-    Requires ``cohort_size`` to upper-bound the sampler's participant count
-    for every round it is dispatched to — the server guarantees this via
-    ``ClientSampler.cohort_bucket`` (``SamplingSchedule.bucket_for`` for
-    the default uniform sampler).  Under a non-uniform sampler the cohort
-    gather is keyed by the sampler's ids: the selection math runs on the
-    full (M,)-shaped arrays exactly as in the oracle, and the cohort
-    buffer gathers the ``part > 0`` ids (sorted ascending, padded with the
-    lowest-id non-participants) so the weighted reductions see the same
-    nonzero terms in the same order — bit-exact vs the oracle.
-    """
-    if not (0 < cohort_size <= cfg.num_clients):
-        raise ValueError(
-            f"cohort_size {cohort_size} not in (0, {cfg.num_clients}]")
-    attack = _active_attack(attack)
-    uses_drift = cfg.client.objective.uses_drift
-
-    if _is_plain(sampler, hetero, attack):
-        apply_wire, agg_fn = _resolve_policies(codec, aggregator)
-
-        def plain_impl(params, residuals, drift, client_batches, n_samples,
-                       t, key):
-            with jax.named_scope("fl.local_update"):
-                sample_key, mask_key = jax.random.split(key)
-                cohort_ids, valid = cohort_select(
-                    sample_key, schedule, t, cfg.num_clients, cohort_size)
-
-                def gather(x):
-                    return jnp.take(x, cohort_ids, axis=0)
-
-                cohort_batches = jax.tree.map(gather, client_batches)
-                cohort_res = jax.tree.map(gather, residuals)
-                cohort_drift = jax.tree.map(gather, drift)
-                mask_keys = jnp.take(
-                    jax.random.split(mask_key, cfg.num_clients), cohort_ids,
-                    axis=0)
-
-                uploads, new_res, new_drift, losses = stacked_client_update(
-                    loss_fn, params, cohort_batches, mask_keys, cfg.client,
-                    cohort_res, cfg.error_feedback, cohort_drift)
-
-            wired = apply_wire(uploads)
-            with jax.named_scope("fl.aggregate"):
-                finite = _finite_rows(wired)
-                weights = valid * jnp.take(n_samples, cohort_ids) * finite
-                new_params = agg_fn(params, _zero_rows(wired, finite), weights,
-                                    cfg.client.upload)
-
-                def scatter_back(full_old, rows, cohort_old, commit):
-                    def scatter(old, new, old_cohort):
-                        vm = commit.reshape((-1,) + (1,) * (new.ndim - 1))
-                        kept = jnp.where(vm > 0, new, old_cohort)
-                        return old.at[cohort_ids].set(kept)
-
-                    return jax.tree.map(scatter, full_old, rows, cohort_old)
-
-                if cfg.error_feedback:
-                    if wired is not uploads:
-                        # Same wire-loss feedback as the oracle round
-                        # (bit-exact equivalence holds: both engines adjust
-                        # identically).
-                        new_res = _wire_feedback(new_res, uploads, wired)
-                    new_residuals = scatter_back(residuals, new_res,
-                                                 cohort_res, valid * finite)
-                else:
-                    new_residuals = residuals
-
-                if uses_drift:
-                    new_drift = scatter_back(drift, new_drift, cohort_drift,
-                                             valid * finite)
-                else:
-                    new_drift = drift
-
-                metrics = {
-                    "mean_loss": jnp.sum(losses * valid)
-                    / jnp.maximum(jnp.sum(valid), 1.0),
-                    "num_sampled": jnp.sum(valid),
-                    "quarantined": jnp.sum(valid * (1.0 - finite)),
-                }
-            return new_params, new_residuals, new_drift, metrics
-
-        return _wrap_plain(plain_impl, uses_drift)
-
-    smp, drop = _round_extras(sampler, hetero, cfg)
-    _, agg_fn = _resolve_policies(codec, aggregator, smp.normalize)
-    compute = make_cohort_compute(loss_fn, schedule, cfg, cohort_size,
-                                  codec=codec, sampler=sampler, attack=attack)
-    adv = None
-    if attack is not None:
-        adv = jnp.asarray(attack.adversary_mask(cfg.num_clients),
-                          jnp.float32)
-
-    def round_impl(params, residuals, drift, norms, client_batches,
-                   n_samples, t, key):
-        with jax.named_scope("fl.local_update"):
-            sample_key, mask_key, drop_key = _split_round_key(
-                key, drop is not None)
-        # The client-side sweep (selection → gather → updates → wire →
-        # adversary injection) is the engine-shared compute; everything
-        # below is this engine's barrier: dropout draw, quarantine gate,
-        # one-shot aggregation, state commit.
-        c = compute(params, residuals, drift, norms, client_batches,
-                    n_samples, t, sample_key, mask_key)
-        part, cohort_ids = c["part"], c["cohort_ids"]
-        uploads, new_res, wired = c["uploads"], c["new_res"], c["wired"]
-        losses, payload = c["losses"], c["attacked"]
-        with jax.named_scope("fl.aggregate"):
-            finite = _finite_rows(payload)
-            arrived, weights = _apply_dropout(part, c["weights"], drop,
-                                              drop_key, smp.normalize)
-
-            def gather(x):
-                return jnp.take(x, cohort_ids, axis=0)
-
-            valid = gather(part)
-            arr_c = gather(arrived)
-            w_c = gather(weights) * finite
-            new_params = agg_fn(params, _zero_rows(payload, finite), w_c,
-                                cfg.client.upload)
-
-            def scatter_back(full_old, rows, cohort_old, commit):
-                def scatter(old, new, old_cohort):
-                    am = commit.reshape((-1,) + (1,) * (new.ndim - 1))
-                    kept = jnp.where(am > 0, new, old_cohort)
-                    return old.at[cohort_ids].set(kept)
-
-                return jax.tree.map(scatter, full_old, rows, cohort_old)
-
-            if cfg.error_feedback:
-                # EF feedback stays on the HONEST (uploads, wired) pair — see
-                # the oracle body.
-                if wired is not uploads:
-                    new_res = _wire_feedback(new_res, uploads, wired)
-                new_residuals = scatter_back(residuals, new_res,
-                                             c["cohort_res"], arr_c * finite)
-            else:
-                new_residuals = residuals
-
-            if uses_drift:
-                new_drift = scatter_back(drift, c["new_drift"],
-                                         c["cohort_drift"], arr_c * finite)
-            else:
-                new_drift = drift
-
-            new_norms = norms
-            if smp.adaptive:
-                obs = _row_l2(payload)
-                old_c = gather(norms)
-                upd = jnp.where(arr_c * finite > 0,
-                                (1.0 - smp.ema) * old_c + smp.ema * obs, old_c)
-                new_norms = norms.at[cohort_ids].set(upd)
-
-            # Same empty-round convention as the oracle body: NaN, not 0.0.
-            n_part = jnp.sum(part)
-            metrics = {
-                "mean_loss": jnp.where(
-                    n_part > 0,
-                    jnp.sum(losses * valid) / jnp.maximum(jnp.sum(valid), 1.0),
-                    jnp.nan),
-                "num_sampled": n_part,
-                "quarantined": jnp.sum(arr_c * (1.0 - finite)),
-            }
-            if attack is not None:
-                metrics["num_adversarial"] = jnp.sum(part * adv)
-            if drop is not None:
-                metrics["part_mask"] = part
-                metrics["arrived_mask"] = arrived
-                metrics["num_arrived"] = jnp.sum(arrived)
-        return new_params, new_residuals, new_drift, new_norms, metrics
-
-    return _wrap_round(round_impl, uses_drift, smp.adaptive)
+    return make_cohort_round(loss_fn, schedule, cfg, cfg.num_clients,
+                             codec=codec, aggregator=aggregator,
+                             sampler=sampler, hetero=hetero, attack=attack)
 
 
 def make_cohort_scan(loss_fn: Callable, schedule: SamplingSchedule,
@@ -823,20 +671,12 @@ def make_cohort_scan(loss_fn: Callable, schedule: SamplingSchedule,
     convention, threaded through the scan carry.  Bit-identical to calling
     the single-round function in a Python loop (same round body, scan just
     removes per-round dispatch)."""
-    if not (0 < cohort_size <= cfg.num_clients):
-        raise ValueError(
-            f"cohort_size {cohort_size} not in (0, {cfg.num_clients}]")
-    kw = dict(codec=codec, aggregator=aggregator, sampler=sampler,
-              hetero=hetero, attack=attack)
-    if cohort_size == cfg.num_clients:
-        round_fn = make_federated_round(loss_fn, schedule, cfg, **kw)
-    else:
-        round_fn = make_cohort_round(loss_fn, schedule, cfg, cohort_size,
-                                     **kw)
-
+    round_fn = make_cohort_round(loss_fn, schedule, cfg, cohort_size,
+                                 codec=codec, aggregator=aggregator,
+                                 sampler=sampler, hetero=hetero,
+                                 attack=attack)
     adaptive = sampler is not None and sampler.adaptive
-    uses_drift = cfg.client.objective.uses_drift
-    n_state = 2 + int(uses_drift) + int(adaptive)
+    n_state = 2 + int(cfg.client.objective.uses_drift) + int(adaptive)
 
     def scan_fn(*args):
         state = tuple(args[:n_state])
@@ -853,124 +693,17 @@ def make_cohort_scan(loss_fn: Callable, schedule: SamplingSchedule,
     return scan_fn
 
 
-# ---------------------------------------------------------------------------
-# Store-form round (DESIGN.md §11)
-# ---------------------------------------------------------------------------
-# The two engines above close over the full (M, …) residual arrays: gather
-# and scatter happen INSIDE the round program, so the dense stack must exist
-# as a program input.  The store form splits the round at exactly that
-# boundary so residual ownership can move into a
-# ``repro.core.client_store.ClientStateStore`` (dense oracle or sharded slot
-# pool) and the program only ever sees cohort-shaped rows:
-#
-#     select(norms, n_samples, t, sample_key)          [jit, (M,) arrays]
-#         -> part, weights, cohort_ids
-#     store.gather(cohort_ids)                          [host boundary]
-#         -> cohort_res
-#     body(params, cohort_res, cohort_batches, cohort_ids,
-#          part, weights, norms, mask_key, drop_key)    [jit, cohort-shaped]
-#         -> new_params, new_rows, commit, norm_upd, metrics
-#     store.scatter(cohort_ids, new_rows, commit)       [host boundary]
-#     store.update_norms(cohort_ids, norm_upd)
-#
-# Equivalence with the in-program engines is by the same construction
-# argument as cohort-vs-oracle: the participant set, per-client mask keys
-# and all per-row math are identical; cohort ids are sorted ascending so
-# weighted reductions visit participants in client-id order (padding rows
-# contribute exact zeros); and the store's commit-masked scatter is the very
-# ``where(commit, new, old) → at[ids].set`` the in-program scatter ran.
-# Padding rows never commit, so a sharded gather returning zeros for a
-# client the window forgot can only differ from dense on rows whose output
-# is masked out of every reduction and never written back.
-
-
-def make_store_selection(schedule: SamplingSchedule, cfg: FederatedConfig,
-                         cohort_size: int, *, sampler=None):
-    """The round's *selection head*, jittable in isolation.
-
-    Returns ``select(norms, n_samples, t, sample_key) -> (part, weights,
-    cohort_ids)``: the participation draw on the full ``(M,)`` arrays
-    (identical ops to the in-program selection of
-    :func:`make_cohort_compute`) plus the sorted cohort-id buffer —
-    everything the host needs to gather residual rows through a
-    :class:`~repro.core.client_store.ClientStateStore` before dispatching
-    the cohort-shaped body.  Pass ``norms=None`` for non-adaptive samplers.
-    """
-    if not (0 < cohort_size <= cfg.num_clients):
-        raise ValueError(
-            f"cohort_size {cohort_size} not in (0, {cfg.num_clients}]")
-    smp = sampler if sampler is not None else UniformSampler()
-
-    def select(norms, n_samples, t, sample_key):
-        with jax.named_scope("fl.local_update"):
-            M = cfg.num_clients
-            part, weights = smp.select(sample_key, schedule, t, M, n_samples,
-                                       norms)
-            ids = jnp.arange(M, dtype=jnp.int32)
-            order = jnp.argsort(jnp.where(part > 0, ids, ids + M))
-            cohort_ids = jnp.sort(order[:cohort_size])
-        return part, weights, cohort_ids
-
-    return select
-
-
-def make_store_compute(loss_fn: Callable, cfg: FederatedConfig, *,
-                       codec=None, attack=None):
-    """Cohort-shaped client sweep over PRE-GATHERED residual rows.
-
-    The store-form sibling of :func:`make_cohort_compute`: selection and
-    the residual gather already happened outside the program, so this is
-    the pure sweep — local updates → wire round-trip → adversary
-    injection.  Returns ``compute(params, cohort_res, cohort_batches,
-    cohort_ids, mask_key, cohort_drift=None) -> dict`` with keys
-    ``uploads`` / ``wired`` / ``attacked`` / ``new_res`` / ``new_drift`` /
-    ``losses`` (same meanings as :func:`make_cohort_compute`'s;
-    ``cohort_drift`` carries the pre-gathered FedDyn drift rows when the
-    objective uses them).  Per-client mask keys are row i of
-    ``split(mask_key, M)`` exactly as in every other engine, so client i's
-    masking draw does not depend on which execution form ran it.
-    """
-    attack = _active_attack(attack)
-    adv = None
-    if attack is not None:
-        adv = jnp.asarray(attack.adversary_mask(cfg.num_clients),
-                          jnp.float32)
-
-    def compute(params, cohort_res, cohort_batches, cohort_ids, mask_key,
-                cohort_drift=None):
-        with jax.named_scope("fl.local_update"):
-            M = cfg.num_clients
-            mask_keys = jnp.take(
-                jax.random.split(mask_key, M), cohort_ids, axis=0)
-            uploads, new_res, new_drift, losses = stacked_client_update(
-                loss_fn, params, cohort_batches, mask_keys, cfg.client,
-                cohort_res, cfg.error_feedback, cohort_drift)
-        wired = roundtrip_stacked(codec, uploads)
-        attacked = _attack_payload(attack, wired, adv, mask_key, M,
-                                   cohort_ids=cohort_ids)
-        return {
-            "uploads": uploads,
-            "new_res": new_res,
-            "new_drift": new_drift,
-            "losses": losses,
-            "wired": wired,
-            "attacked": attacked,
-        }
-
-    return compute
-
-
 @dataclasses.dataclass(frozen=True)
 class StoreRound:
     """The store-form round program, split at the store boundary.
 
     ``select`` and ``body`` are independently jittable; the driver
-    (``FederatedServer._run_store``) moves residual rows between them
+    (``FederatedServer._run_store``) moves state rows between them
     through a :class:`~repro.core.client_store.ClientStateStore`.  The
     flags tell the driver which optional state the pieces consume."""
 
     select: Callable   # (norms, n_samples, t, sample_key) -> (part, w, ids)
-    body: Callable     # cohort-shaped barrier; see make_store_round
+    body: Callable     # cohort-shaped sweep + barrier; see make_store_round
     adaptive: bool     # body consumes/updates the (M,) norm EMA
     with_drop: bool    # round key splits 3 ways (hetero dropout draw)
     error_feedback: bool  # residual rows need scattering back
@@ -981,95 +714,38 @@ def make_store_round(loss_fn: Callable, schedule: SamplingSchedule,
                      cfg: FederatedConfig, cohort_size: int, *,
                      codec=None, aggregator=None, sampler=None, hetero=None,
                      attack=None) -> StoreRound:
-    """Store-form sibling of :func:`make_cohort_round`.
+    """Store-form sibling of :func:`make_cohort_round`: the same pieces,
+    with the state gather/scatter OUTSIDE the programs (DESIGN.md §11)::
 
-    Same math as the generalized cohort body, but state gather/scatter
-    are OUTSIDE the program: ``body(params, cohort_res, cohort_drift,
-    cohort_batches, cohort_ids, part, weights, norms, mask_key, drop_key)
-    -> (new_params, new_rows, drift_rows, commit, norm_upd, metrics)``
-    where ``new_rows`` are the finalized post-round residual candidates
-    (wire-loss feedback already folded in), ``drift_rows`` the post-round
-    FedDyn drift candidates (None unless the objective uses drift),
-    ``commit`` is the per-cohort-row "this upload applied" mask
-    (``arrived × finite`` — ALWAYS computed; the driver gates the residual
-    scatter on ``error_feedback`` and the drift scatter on ``uses_drift``),
-    and ``norm_upd`` is the cohort's updated norm-EMA rows (None for
-    non-adaptive samplers; rows with no arrival carry the old value, so
-    setting them back is a no-op).
+        select(norms, n_samples, t, sample_key)     -> part, weights, ids
+        store.gather(ids)                           -> cohort_res, drift
+        body(params, cohort_res, cohort_drift, cohort_batches, cohort_ids,
+             part, weights, norms, mask_key, drop_key)
+            -> (new_params, new_rows, drift_rows, commit, norm_upd, metrics)
+        store.scatter(ids, new_rows, commit); store.update_norms(ids, ...)
 
-    Unlike the in-program engines there is no separate plain path: the
-    generalized body IS bit-exact for plain rounds too — the uniform
-    sampler's selection draw matches ``participation_mask``, and the only
-    difference from ``cohort_select``'s buffer is WHICH non-participants
-    pad the cohort, rows that contribute exact zeros to every reduction
-    and never commit.
+    ``body`` is the sweep then the barrier (see :func:`_make_barrier` for
+    its outputs).  ``commit`` is ALWAYS computed; the driver gates the
+    residual scatter on ``error_feedback`` and the drift scatter on
+    ``uses_drift``.  Padding rows never commit, so a sharded gather
+    returning zeros for a client the window forgot can only differ from
+    dense on rows masked out of every reduction and never written back.
     """
-    if not (0 < cohort_size <= cfg.num_clients):
-        raise ValueError(
-            f"cohort_size {cohort_size} not in (0, {cfg.num_clients}]")
-    attack = _active_attack(attack)
-    smp, drop = _round_extras(sampler, hetero, cfg)
-    _, agg_fn = _resolve_policies(codec, aggregator, smp.normalize)
-    compute = make_store_compute(loss_fn, cfg, codec=codec, attack=attack)
-    select = make_store_selection(schedule, cfg, cohort_size, sampler=sampler)
-    adv = None
-    if attack is not None:
-        adv = jnp.asarray(attack.adversary_mask(cfg.num_clients),
-                          jnp.float32)
+    select = make_store_selection(schedule, cfg, cohort_size,
+                                  sampler=sampler)
+    sweep = make_store_compute(loss_fn, cfg, codec=codec, attack=attack)
+    barrier, smp, with_drop = _make_barrier(
+        cfg, aggregator=aggregator, sampler=sampler, hetero=hetero,
+        attack=attack)
 
     def body(params, cohort_res, cohort_drift, cohort_batches, cohort_ids,
              part, weights, norms, mask_key, drop_key):
-        c = compute(params, cohort_res, cohort_batches, cohort_ids, mask_key,
-                    cohort_drift)
-        uploads, new_res, wired = c["uploads"], c["new_res"], c["wired"]
-        losses, payload = c["losses"], c["attacked"]
-        drift_rows = c["new_drift"]
-        with jax.named_scope("fl.aggregate"):
-            finite = _finite_rows(payload)
-            arrived, weights = _apply_dropout(part, weights, drop, drop_key,
-                                              smp.normalize)
-
-            def gather(x):
-                return jnp.take(x, cohort_ids, axis=0)
-
-            valid = gather(part)
-            arr_c = gather(arrived)
-            w_c = gather(weights) * finite
-            new_params = agg_fn(params, _zero_rows(payload, finite), w_c,
-                                cfg.client.upload)
-            commit = arr_c * finite
-            if cfg.error_feedback:
-                # EF feedback on the HONEST (uploads, wired) pair, exactly as
-                # in the in-program engines.
-                if wired is not uploads:
-                    new_res = _wire_feedback(new_res, uploads, wired)
-
-            norm_upd = None
-            if smp.adaptive:
-                obs = _row_l2(payload)
-                old_c = gather(norms)
-                norm_upd = jnp.where(arr_c * finite > 0,
-                                     (1.0 - smp.ema) * old_c + smp.ema * obs,
-                                     old_c)
-
-            n_part = jnp.sum(part)
-            metrics = {
-                "mean_loss": jnp.where(
-                    n_part > 0,
-                    jnp.sum(losses * valid) / jnp.maximum(jnp.sum(valid), 1.0),
-                    jnp.nan),
-                "num_sampled": n_part,
-                "quarantined": jnp.sum(arr_c * (1.0 - finite)),
-            }
-            if attack is not None:
-                metrics["num_adversarial"] = jnp.sum(part * adv)
-            if drop is not None:
-                metrics["part_mask"] = part
-                metrics["arrived_mask"] = arrived
-                metrics["num_arrived"] = jnp.sum(arrived)
-        return new_params, new_res, drift_rows, commit, norm_upd, metrics
+        c = sweep(params, cohort_res, cohort_drift, cohort_batches,
+                  cohort_ids, mask_key)
+        return barrier(params, dict(c, part=part, weights=weights,
+                                    cohort_ids=cohort_ids), norms, drop_key)
 
     return StoreRound(select=select, body=body, adaptive=smp.adaptive,
-                      with_drop=drop is not None,
+                      with_drop=with_drop,
                       error_feedback=cfg.error_feedback,
                       uses_drift=cfg.client.objective.uses_drift)

@@ -75,7 +75,8 @@ import numpy as np
 from repro.core.client import local_update_flops
 from repro.core.client_store import ClientStateStore, DenseStore
 from repro.core.compression import pytree_num_params
-from repro.core.federated import FederatedConfig, _split_round_key
+from repro.core.federated import (FederatedConfig, _split_round_key,
+                                  aot_compile)
 from repro.core.hetero import simulate_round
 from repro.core.sampling import SamplingSchedule
 
@@ -168,7 +169,9 @@ class FederatedServer:
         self.scan_rounds = scan_rounds
         self._loss_fn = loss_fn
         self._key = jax.random.PRNGKey(seed)
-        self._compiled: Dict[tuple, Any] = {}   # (bucket, seg_len) -> executable
+        # AOT executables, keyed by (program, bucket, seg_len, input
+        # avals); see federated.aot_compile.
+        self._compiled: Dict[tuple, Any] = {}
         self._store_programs: Dict[int, Any] = {}  # bucket -> StoreRound
         # All per-client server state — EF residuals, the adaptive
         # samplers' norm EMAs (ones = "everyone looks equally important"
@@ -285,26 +288,6 @@ class FederatedServer:
             return build_round(self.strategy, self._loss_fn, M, form="full")
         return build_round(self.strategy, self._loss_fn, M,
                            form="cohort", cohort_size=bucket)
-
-    def _get_compiled(self, bucket: int, seg_len: int, args):
-        """AOT-compile (once) the program for this bucket/segment shape.
-        Returns ``(executable, compile_s)`` — compile_s is 0 on cache hit.
-        The key includes the input avals so a later ``run()`` with
-        differently-shaped data recompiles instead of hitting a stale
-        executable (AOT calls don't retrace the way plain jit does)."""
-        avals = tuple((tuple(leaf.shape), str(leaf.dtype))
-                      for leaf in jax.tree_util.tree_leaves(args))
-        cache_key = (bucket, seg_len, avals)
-        hit = self._compiled.get(cache_key)
-        if hit is not None:
-            return hit, 0.0
-        with jax.profiler.TraceAnnotation("fl.compile", bucket=bucket):
-            fn = self._round_program(bucket, seg_len)
-            t0 = time.perf_counter()
-            compiled = jax.jit(fn).lower(*args).compile()
-            compile_s = time.perf_counter() - t0
-        self._compiled[cache_key] = compiled
-        return compiled, compile_s
 
     def _segments(self, rounds: int, eval_rounds, start: int = 0) -> List[tuple]:
         """Split start+1..start+rounds into (bucket, [t...]) segments:
@@ -432,7 +415,9 @@ class FederatedServer:
         if self._adaptive:
             state.append(self._norms)
         args = (*state, client_batches, n_samples, t_arg, key_arg)
-        compiled, compile_s = self._get_compiled(bucket, seg_len, args)
+        compiled, compile_s = aot_compile(
+            self._compiled, "round", bucket,
+            lambda: self._round_program(bucket, seg_len), args, seg_len)
         t0 = time.perf_counter()
         out = compiled(*args)
         self.params, self._residuals = out[0], out[1]
@@ -450,7 +435,8 @@ class FederatedServer:
                         eval_data):
         """Pull one segment's metrics to the host and append a
         ``RoundRecord`` per round (with the simulated-fleet clock and the
-        eval where they apply)."""
+        eval where they apply).  ``metrics`` leaves are stacked per round,
+        or scalars for a one-round segment (the store loop's)."""
         seg_len = len(ts)
         num_sampled = np.atleast_1d(np.asarray(metrics["num_sampled"]))
         mean_loss = np.atleast_1d(np.asarray(metrics["mean_loss"]))
@@ -560,23 +546,6 @@ class FederatedServer:
             self._store_programs[bucket] = prog
         return prog
 
-    def _aot(self, tag: str, bucket: int, fn, args):
-        """AOT-compile ``fn`` once per (tag, bucket, input avals); returns
-        ``(executable, compile_s)`` — same caching discipline as
-        :meth:`_get_compiled`, keyed separately because the store-form
-        round is two programs, not one."""
-        avals = tuple((tuple(leaf.shape), str(leaf.dtype))
-                      for leaf in jax.tree_util.tree_leaves(args))
-        cache_key = (tag, bucket, avals)
-        hit = self._compiled.get(cache_key)
-        if hit is not None:
-            return hit, 0.0
-        t0 = time.perf_counter()
-        compiled = jax.jit(fn).lower(*args).compile()
-        compile_s = time.perf_counter() - t0
-        self._compiled[cache_key] = compiled
-        return compiled, compile_s
-
     def _run_store(self, client_batches, n_samples, rounds, eval_rounds,
                    eval_data, gamma, wire_bytes, flops_per_client):
         """Sync round loop through the client-state store: selection and
@@ -601,8 +570,9 @@ class FederatedServer:
             norms = store.norms if prog.adaptive else None
 
             sel_args = (norms, n_samples, t_arg, sample_key)
-            sel_fn, compile_s = self._aot("store-sel", bucket, prog.select,
-                                          sel_args)
+            sel_fn, compile_s = aot_compile(self._compiled, "store-sel",
+                                            bucket, lambda: prog.select,
+                                            sel_args)
             t0 = time.perf_counter()
             part, weights, cohort_ids = sel_fn(*sel_args)
             ids_np = np.asarray(cohort_ids)
@@ -620,8 +590,9 @@ class FederatedServer:
             body_args = (self.params, cohort_res, cohort_drift,
                          cohort_batches, cohort_ids, part, weights, norms,
                          mask_key, drop_key)
-            body_fn, body_compile_s = self._aot("store-body", bucket,
-                                                prog.body, body_args)
+            body_fn, body_compile_s = aot_compile(
+                self._compiled, "store-body", bucket, lambda: prog.body,
+                body_args)
             compile_s += body_compile_s
             t0 = time.perf_counter()
             (self.params, new_rows, drift_rows, commit, norm_upd,
@@ -641,32 +612,9 @@ class FederatedServer:
             if prog.adaptive:
                 store.update_norms(ids_np, norm_upd)
 
-            m_t = float(np.asarray(metrics["num_sampled"]))
-            rec = RoundRecord(
-                round=t,
-                num_sampled=int(m_t),
-                mean_loss=float(np.asarray(metrics["mean_loss"])),
-                transport_units=m_t * gamma,
-                transport_bytes=int(m_t) * wire_bytes,
-                wall_s=wall,
-                compile_s=compile_s,
-                cohort_size=bucket,
-                quarantined=int(np.asarray(metrics["quarantined"])),
-                adversarial=int(np.asarray(
-                    metrics["num_adversarial"]))
-                if "num_adversarial" in metrics else 0,
-            )
-            if self._traits is not None:
-                sim = simulate_round(self._traits,
-                                     np.asarray(metrics["part_mask"]),
-                                     np.asarray(metrics["arrived_mask"]),
-                                     float(flops_per_client), wire_bytes)
-                rec.sim_round_s = sim["sim_round_s"]
-                rec.straggler_s = sim["straggler_s"]
-                rec.dropped = sim["dropped"]
-            if t in eval_rounds:
-                rec.eval_metric = float(self.eval_fn(self.params, eval_data))
-            self.history.append(rec)
+            self._record_segment(bucket, [t], metrics, wall, compile_s,
+                                 gamma, wire_bytes, flops_per_client,
+                                 eval_rounds, eval_data)
             self._round = t
         return self.history
 

@@ -166,7 +166,7 @@ class Aggregator:
     ``ht_compatible=False`` declares the rule unable to honour HT weights
     at all (Krum-family: candidate selection ignores weight magnitudes);
     building a round that pairs such an aggregator with an HT sampler
-    raises a ``TypeError`` (``repro.core.federated._resolve_policies``).
+    raises a ``TypeError`` (``repro.core.federated._resolve_aggregator``).
     """
 
     name: str

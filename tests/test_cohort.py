@@ -20,8 +20,10 @@ from conftest import given, settings, st
 
 from repro.core import (ClientConfig, DynamicSampling, FederatedConfig,
                         FederatedServer, MaskingConfig, StaticSampling)
+from repro.core.codecs import SparseCodec
 from repro.core.federated import (cohort_select, make_cohort_round,
-                                  make_cohort_scan, make_federated_round)
+                                  make_cohort_scan, make_federated_round,
+                                  make_store_round)
 from repro.core.sampling import participation_mask
 
 
@@ -147,6 +149,72 @@ def test_cohort_scan_matches_round_loop():
     _assert_trees_equal(r, r_s)
     np.testing.assert_allclose(np.asarray(met_s["mean_loss"]),
                                np.asarray(losses), rtol=1e-6)
+
+
+@pytest.mark.parametrize("mode,codec", [
+    ("none", None),
+    ("selective", None),
+    ("selective", SparseCodec(gamma=0.4, min_leaf_size=256)),
+], ids=["dense", "selective", "selective-coo"])
+def test_padded_bucket_forms_bit_identical(mode, codec):
+    """A uniform round whose bucket is padded (m_t < bucket < M), error
+    feedback on: the cohort round, the store round driven by hand (select
+    → gather → body → scatter) and the oracle give bit-identical params,
+    residuals and metrics, round after round."""
+    M, rounds = 16, 3
+    loss_fn, params, batches, n = _problem(M, dim=128)   # w: 384 > 256
+    sched = StaticSampling(initial_rate=0.3, min_clients=2)
+    m = sched.num_clients_host(1, M)
+    bucket = sched.bucket_for(m, M)
+    assert m < bucket < M, (m, bucket)
+    cfg = FederatedConfig(
+        num_clients=M,
+        client=ClientConfig(local_epochs=1, learning_rate=0.1,
+                            masking=MaskingConfig(mode=mode, gamma=0.4)),
+        error_feedback=True)
+    oracle = jax.jit(make_federated_round(loss_fn, sched, cfg, codec=codec))
+    cohort = jax.jit(make_cohort_round(loss_fn, sched, cfg, bucket,
+                                       codec=codec))
+    store = make_store_round(loss_fn, sched, cfg, bucket, codec=codec)
+    select, body = jax.jit(store.select), jax.jit(store.body)
+    assert not store.with_drop and not store.adaptive
+
+    def by_hand(p, r, t, key):
+        sample_key, mask_key = jax.random.split(key)
+        part, weights, ids = select(None, n, t, sample_key)
+        rows = jax.tree.map(lambda x: jnp.take(x, ids, axis=0), (r, batches))
+        p, new_rows, _, commit, norm_upd, met = body(
+            p, rows[0], None, rows[1], ids, part, weights, None, mask_key,
+            None)
+        assert norm_upd is None
+
+        def scatter(old, new):
+            keep = commit.reshape((-1,) + (1,) * (new.ndim - 1)) > 0
+            return old.at[ids].set(jnp.where(keep, new, old[ids]))
+
+        return p, jax.tree.map(scatter, r, new_rows), met
+
+    res0 = _zero_residuals(params, M)
+    states = {"oracle": (params, res0), "cohort": (params, res0),
+              "store": (params, res0)}
+    key = jax.random.PRNGKey(11)
+    for t in range(1, rounds + 1):
+        key, sub = jax.random.split(key)
+        t_arg = jnp.asarray(t, jnp.float32)
+        out = {"oracle": oracle(*states["oracle"], batches, n, t_arg, sub),
+               "cohort": cohort(*states["cohort"], batches, n, t_arg, sub),
+               "store": by_hand(*states["store"], t_arg, sub)}
+        for form, (p, r, met) in out.items():
+            states[form] = (p, r)
+            assert int(met["num_sampled"]) == m
+            _assert_trees_equal(p, out["oracle"][0])
+            _assert_trees_equal(r, out["oracle"][1])
+            for k in ("mean_loss", "num_sampled", "quarantined"):
+                np.testing.assert_array_equal(
+                    np.asarray(met[k]), np.asarray(out["oracle"][2][k]),
+                    err_msg=f"{form} {k} round {t}")
+    if mode == "selective":     # masking binds: residuals carry mass
+        assert float(jnp.abs(states["oracle"][1]["w"]).max()) > 0.0
 
 
 def test_server_engines_match():
